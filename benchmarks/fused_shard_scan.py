@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import emit
 from repro.api import make_tuner_db
+from repro.compile_cache import compile_cache_off
 from repro.core import engine as eng
 from repro.core.index import make_sharded_index, sharded_build_pages_vap
 from repro.core.table import shard_table
@@ -110,8 +111,11 @@ def run(n_queries: int = 16, n_rows: int = 4_096, page_size: int = 128,
                 total_q += B
             return (time.perf_counter() - t0) / total_q * 1e6
 
-        us_loop = run_bursts(fused=False)
-        us_fused = run_bursts(fused=True)
+        # These records time fresh compiles: a warm persistent cache
+        # would turn them into cache reads.
+        with compile_cache_off():
+            us_loop = run_bursts(fused=False)
+            us_fused = run_bursts(fused=True)
         speedup = us_loop / us_fused
         is_headline = S == HEADLINE_S
         if is_headline:

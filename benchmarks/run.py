@@ -13,9 +13,11 @@ import traceback
 
 
 def bench_kernels():
-    """Pallas filter_agg vs pure-jnp reference (interpret mode on this
-    container -- the comparison point is correctness + call overhead;
-    TPU timings come from real deployments)."""
+    """Pallas filter_agg vs pure-jnp reference.  On the CPU the kernel
+    runs in interpret mode (the comparison point is correctness + call
+    overhead); on a TPU it is the compiled kernel."""
+    import jax
+
     from benchmarks.common import emit
     from repro.bench_db.schema import make_tuner_db
     from repro.kernels import ops
@@ -38,8 +40,12 @@ def bench_kernels():
     us_pal = timed(lambda: ops.scan_table(
         t, (1,), (lo,), (hi,), ts=0, agg_attr=2)[0].block_until_ready())
     emit("kernel.filter_agg_ref_jnp", us_ref, "pure-jnp oracle")
-    emit("kernel.filter_agg_pallas_interpret", us_pal,
-         "pl.pallas_call interpret=True (CPU correctness mode)")
+    if ops.interpret_mode():
+        emit("kernel.filter_agg_pallas_interpret", us_pal,
+             "pl.pallas_call interpret=True (CPU correctness mode)")
+    else:
+        emit("kernel.filter_agg_pallas_compiled", us_pal,
+             f"pl.pallas_call on {jax.devices()[0].device_kind}")
 
 
 def bench_roofline():
@@ -148,6 +154,9 @@ def main() -> None:
             f"run.py: unknown benchmark {args.only!r}; "
             f"known benchmarks: {', '.join(names)}")
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     common.reset_records()
     failures = []
     for name, fn in jobs:

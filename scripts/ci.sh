@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
 # Canonical pre-merge check: lint gate + the fast tier-1 slice on CPU
-# with the Pallas kernels in interpret mode (repro.kernels.ops.INTERPRET
-# is True by default on this container; TPU deployments flip it).
+# with the Pallas kernels in interpret mode (repro.kernels.ops picks
+# interpret mode exactly when JAX's backend is the CPU; on a TPU the
+# kernels compile).
 #
 #   scripts/ci.sh            lint (if ruff installed) + fast slice
 #   scripts/ci.sh --full     lint + everything, incl. @pytest.mark.slow
@@ -36,13 +37,14 @@ RUFF_FORMAT_PATHS=(
     src/repro/serving
 )
 
-# Tracked-artifact gate: bytecode, pytest caches and benchmark JSON
-# must never be committed (.gitignore covers them; this catches
-# force-adds and stale history).  Exception: benchmarks/baselines/
-# holds the COMMITTED trajectory seed the nightly gate falls back to
-# before its cache has a point (see .github/workflows/ci.yml).
+# Tracked-artifact gate: bytecode, pytest caches, the JAX compile
+# cache and benchmark JSON must never be committed (.gitignore covers
+# them; this catches force-adds and stale history).  Exception:
+# benchmarks/baselines/ holds the COMMITTED trajectory seed the
+# nightly gate falls back to before its cache has a point (see
+# .github/workflows/ci.yml).
 tracked_artifacts() {
-    git ls-files | grep -E '(^|/)__pycache__/|\.pyc$|(^|/)\.pytest_cache/|(^|/)BENCH_[^/]*\.json$|(^|/)bench-[^/]*\.json$' \
+    git ls-files | grep -E '(^|/)__pycache__/|\.pyc$|(^|/)\.pytest_cache/|(^|/)\.jax_cache/|(^|/)BENCH_[^/]*\.json$|(^|/)bench-[^/]*\.json$' \
         | grep -v '^benchmarks/baselines/BENCH_[^/]*\.json$' || true
 }
 

@@ -103,7 +103,6 @@ from repro.parallel.mesh import (
     SHARD_AXIS,
     batch_spec,
     make_scan_mesh,
-    shard_map,
     stacked_specs,
 )
 
@@ -1129,8 +1128,8 @@ def pmap_batched_full_table_scan(
 # ``use_kernel`` swaps the table-suffix mask arithmetic for one Pallas
 # kernel launch per locally-owned shard (the non-interpret TPU path
 # with per-chip block shapes, ``kernels.batched_filter_agg``); the
-# engine only selects it off-CPU (``kernels.ops.INTERPRET`` False), so
-# CPU meshes keep the bit-identical jnp bodies.
+# engine only selects it off-CPU (``kernels.ops.interpret_mode()``
+# False), so CPU meshes keep the bit-identical jnp bodies.
 
 
 def _mesh_kernel_suffix(stk, attrs, los, his, tss, agg_attr, starts):
@@ -1185,11 +1184,12 @@ def _mesh_full_fn(mesh, attrs: tuple, agg_attr: int, use_kernel: bool):
             jax.lax.psum(cnts, SHARD_AXIS),
         )
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(stacked_specs(), bspec, bspec, bspec),
         out_specs=(bspec, bspec),
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -1262,11 +1262,12 @@ def _mesh_hybrid_fn(
             start_pages.astype(jnp.int32),
         )
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(stacked_specs(), stacked_specs(), bspec, bspec, bspec),
         out_specs=(bspec, bspec, bspec, bspec),
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -1325,11 +1326,12 @@ def _mesh_hybrid_ps_fn(
             ),
         )
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(stacked_specs(), stacked_specs(), bspec, bspec, bspec),
         out_specs=(bspec, bspec, bspec, bspec, bspec),
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -1408,9 +1410,9 @@ def _mesh_hybrid_masked_fn(
             jax.lax.psum(ents, SHARD_AXIS),
         )
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(
             stacked_specs(),
             stacked_specs(),
@@ -1421,6 +1423,7 @@ def _mesh_hybrid_masked_fn(
             bspec,
         ),
         out_specs=(bspec, bspec, bspec),
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -1454,11 +1457,12 @@ def _mesh_pure_index_fn(
             jax.lax.psum(_sum0(ents), SHARD_AXIS),
         )
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(stacked_specs(), stacked_specs(), bspec, bspec, bspec),
         out_specs=(bspec, bspec, bspec),
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -2057,7 +2061,7 @@ class ScanEngine:
             from repro.kernels import ops as _kops
 
             self.last_tier = "shard_map"
-            mesh_kernel = kernel_ok and not _kops.INTERPRET
+            mesh_kernel = kernel_ok and not _kops.interpret_mode()
             if path == "table":
                 return mesh_batched_full_table_scan(
                     table, attrs, los, his, tss, agg_attr, mesh, mesh_kernel

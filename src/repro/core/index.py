@@ -151,8 +151,11 @@ def _lexsort_merge(kh, kl, rids, capacity: int):
 # VAP: value-agnostic page-wise population (the paper's scheme)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit,
-                   static_argnames=("key_attrs", "pages_per_cycle"))
+# Smallest page bucket of a VAP build step (the tuner's default
+# per-cycle cap is 64 pages).
+_MIN_BUILD_BUCKET = 64
+
+
 def build_pages_vap(index: AdHocIndex, table: Table, key_attrs: tuple,
                     pages_per_cycle: int) -> AdHocIndex:
     """One VAP tuning-cycle step: index the next ``pages_per_cycle`` pages.
@@ -160,16 +163,33 @@ def build_pages_vap(index: AdHocIndex, table: Table, key_attrs: tuple,
     Cost is O(pages_per_cycle * page_size) extraction + one merge of
     the key arrays -- independent of attribute value distribution,
     which is precisely the property Section III-A argues for.
+
+    The page count is a traced operand inside a static power-of-two
+    bucket (at least 64 pages, at most the table), so build quanta of
+    every size up to the bucket share one compiled program: each
+    program sorts the whole index capacity, and compiling one per
+    quantum size dominated a run on the chip.  Pages past the count
+    are masked exactly like pages past the full-page watermark.
     """
+    n = int(pages_per_cycle)
+    bucket = max(_MIN_BUILD_BUCKET, 1 << max(n - 1, 0).bit_length())
+    bucket = min(bucket, max(table.n_pages, n))
+    return _build_pages_vap(index, table, key_attrs,
+                            jnp.asarray(n, jnp.int32), bucket=bucket)
+
+
+@functools.partial(jax.jit, static_argnames=("key_attrs", "bucket"))
+def _build_pages_vap(index: AdHocIndex, table: Table, key_attrs: tuple,
+                     n_pages, bucket: int) -> AdHocIndex:
     psz = table.page_size
     start = index.built_pages
-    page_off = jnp.arange(pages_per_cycle, dtype=jnp.int32)
+    page_off = jnp.arange(bucket, dtype=jnp.int32)
     pages = start + page_off
     # Only *fully populated* pages may be indexed and counted as built:
     # a partially filled watermark page must stay inside the table-scan
     # region, otherwise later appends to it would be invisible.
     full_pages = (table.n_rows // psz).astype(jnp.int32)
-    in_range = pages < full_pages
+    in_range = (page_off < n_pages) & (pages < full_pages)
     pages_c = jnp.clip(pages, 0, table.n_pages - 1)
 
     rows = table.data[pages_c]                      # (P, psz, n_attrs)
@@ -190,7 +210,7 @@ def build_pages_vap(index: AdHocIndex, table: Table, key_attrs: tuple,
     mr = jnp.concatenate([index.rids, new_rids.astype(jnp.int32)])
     mh, ml, mr = _lexsort_merge(mh, ml, mr, index.capacity)
     n_entries = index.n_entries + jnp.sum(valid, dtype=jnp.int32)
-    built = jnp.minimum(start + pages_per_cycle, full_pages)
+    built = jnp.minimum(start + n_pages, full_pages)
     built = jnp.maximum(built, start)  # never regress
     return AdHocIndex(mh, ml, mr, n_entries, built)
 

@@ -18,10 +18,12 @@ Batching amortises both:
   entirely inside that query's indexed prefix skips its compute via
   ``pl.when`` (and, when *every* query in the batch skips the block,
   no query forces the DMA).
-* Each (block, query) step writes its partial (sum, count) to a
-  ``(n_blocks, n_queries)`` output; the wrapper reduces over blocks.
-  Accumulation stays int32 (the engine's documented wraparound
-  semantics).
+* Each (block, query) step adds its partial (sum, count) into the
+  query's slot of two whole-array ``(n_queries,)`` SMEM outputs,
+  zeroed on the first page block.  (A per-step ``(1, 1)`` output
+  block breaks the TPU's (8, 128) tiling rule.)  Accumulation stays
+  int32 (the engine's documented wraparound semantics), so the
+  summation order cannot change the bits.
 
 ``sharded_batched_filter_agg`` extends the same design with a leading
 *shard* grid axis over stacked column planes (``(S, n_pages,
@@ -40,8 +42,8 @@ burst is ONE launch regardless of shard count:
   whole padding *blocks* past a shard's last real block are skipped
   pre-DMA exactly like prefix blocks: the index map clamps the block
   coordinate into the shard's [first-needed, last-real] block range,
-  so skipped steps revisit a resident block and ``pl.when`` zeroes
-  their outputs.
+  so skipped steps revisit a resident block and ``pl.when`` keeps
+  them out of the sums.
 
 Semantics contract: ``ref.batched_filter_agg_ref`` -- per query
 identical to ``ref.masked_filter_agg_ref``.  A single-query batch is
@@ -92,6 +94,24 @@ def tpu_block_pages(
     return bp
 
 
+# Per-query (sum, count) outputs: whole (n_queries,) arrays in SMEM,
+# resident for the whole grid, so each step accumulates into its
+# query's slot.
+_SMEM_OUT = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _query_vector(n_queries):
+    return jax.ShapeDtypeStruct((n_queries,), jnp.int32)
+
+
+def _words_spanned(block_pages):
+    """Most 32-page coverage words one block of ``block_pages`` pages
+    can touch (a block aligned to 32 pages touches exactly its own)."""
+    if block_pages % 32 == 0:
+        return block_pages // 32
+    return -(-block_pages // 32) + 1
+
+
 def _pad_pages(planes, n_pages, block_pages, page_axis):
     """Pad the page axis up to a whole number of blocks; padding rows
     carry begin_ts = INT32_MAX -> never visible."""
@@ -137,10 +157,10 @@ def _batched_kernel(
 
     first_page = blk * block_pages
 
-    @pl.when(first_page + block_pages <= start_page)
-    def _skip():
-        sum_ref[0, 0] = jnp.int32(0)
-        cnt_ref[0, 0] = jnp.int32(0)
+    @pl.when(blk == 0)
+    def _init():
+        sum_ref[q] = jnp.int32(0)
+        cnt_ref[q] = jnp.int32(0)
 
     @pl.when(first_page + block_pages > start_page)
     def _run():
@@ -155,8 +175,8 @@ def _batched_kernel(
         # start_page boundary.
         rows = jax.lax.broadcasted_iota(jnp.int32, p0.shape, 0)
         mask &= (first_page + rows) >= start_page
-        sum_ref[0, 0] = jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
-        cnt_ref[0, 0] = jnp.sum(mask, dtype=jnp.int32)
+        sum_ref[q] += jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
+        cnt_ref[q] += jnp.sum(mask, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
@@ -218,13 +238,12 @@ def batched_filter_agg(
     # innermost query steps revisit the resident block.  Clamping the
     # coordinate up to the batch-wide first needed block makes the
     # skippable prefix revisit THAT block too, so its DMAs are elided
-    # -- the pre-DMA skip (pl.when in the kernel body still zeroes the
-    # prefix outputs per query).
+    # -- the pre-DMA skip (pl.when in the kernel body keeps the prefix
+    # out of every query's sums).
     block = pl.BlockSpec(
         (block_pages, page_size),
         lambda i, q, s: (jnp.maximum(i, s[6, 0]), 0),
     )
-    out_spec = pl.BlockSpec((1, 1), lambda i, q, s: (i, q))
     kernel = functools.partial(_batched_kernel, block_pages=block_pages)
     sums, cnts = pl.pallas_call(
         kernel,
@@ -232,18 +251,12 @@ def batched_filter_agg(
             num_scalar_prefetch=1,
             grid=(n_blocks, n_queries),
             in_specs=[block] * 5,
-            out_specs=[out_spec, out_spec],
+            out_specs=[_SMEM_OUT, _SMEM_OUT],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, n_queries), jnp.int32),
-            jax.ShapeDtypeStruct((n_blocks, n_queries), jnp.int32),
-        ],
+        out_shape=[_query_vector(n_queries), _query_vector(n_queries)],
         interpret=interpret,
     )(scalars, pred0, pred1, agg, begin_ts, end_ts)
-    return (
-        jnp.sum(sums, axis=0, dtype=jnp.int32),
-        jnp.sum(cnts, axis=0, dtype=jnp.int32),
-    )
+    return sums, cnts
 
 
 def _sharded_kernel(
@@ -283,10 +296,10 @@ def _sharded_kernel(
     first_page = blk * block_pages
     live = (first_page + block_pages > start_page) & (blk <= last_blk)
 
-    @pl.when(jnp.logical_not(live))
-    def _skip():
-        sum_ref[0, 0, 0] = jnp.int32(0)
-        cnt_ref[0, 0, 0] = jnp.int32(0)
+    @pl.when((s == 0) & (blk == 0))
+    def _init():
+        sum_ref[q] = jnp.int32(0)
+        cnt_ref[q] = jnp.int32(0)
 
     @pl.when(live)
     def _run():
@@ -300,8 +313,8 @@ def _sharded_kernel(
         # Blocks are (1, block_pages, page_size); the page axis is 1.
         rows = jax.lax.broadcasted_iota(jnp.int32, p0.shape, 1)
         mask &= (first_page + rows) >= start_page
-        sum_ref[0, 0, 0] = jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
-        cnt_ref[0, 0, 0] = jnp.sum(mask, dtype=jnp.int32)
+        sum_ref[q] += jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
+        cnt_ref[q] += jnp.sum(mask, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
@@ -330,8 +343,8 @@ def sharded_batched_filter_agg(
     int32; ``local_pages`` (S,) int32 gives each shard's real
     (pre-padding) page count so whole padding blocks skip their DMA.
     Returns (sums, counts), each (n_queries,) int32 -- the partials
-    reduced over shards and blocks (int32 addition is associative, so
-    the reduction order cannot change the bits).
+    accumulated over shards and blocks (int32 addition is associative,
+    so the accumulation order cannot change the bits).
     """
     n_shards, n_pages, page_size = pred0.shape
     n_queries = los0.shape[0]
@@ -349,7 +362,7 @@ def sharded_batched_filter_agg(
     # last block holding real pages].  The index map clamps the block
     # coordinate into this window, so prefix blocks AND trailing
     # padding blocks revisit a resident block (their DMAs are elided);
-    # the kernel body zeroes their outputs.
+    # the kernel body adds nothing for them.
     first_blk = jnp.min(start_pages, axis=1) // block_pages
     last_blk = jnp.maximum(-(-local_pages // block_pages) - 1, 0)
     last_blk = jnp.minimum(last_blk, n_blocks - 1)
@@ -363,9 +376,6 @@ def sharded_batched_filter_agg(
         return (s, jnp.clip(i, bi[s, 0], bi[s, 1]), 0)
 
     block = pl.BlockSpec((1, block_pages, page_size), _imap)
-    out_spec = pl.BlockSpec(
-        (1, 1, 1), lambda s, i, q, qp, stt, bi: (s, i, q)
-    )
     kernel = functools.partial(_sharded_kernel, block_pages=block_pages)
     sums, cnts = pl.pallas_call(
         kernel,
@@ -373,18 +383,12 @@ def sharded_batched_filter_agg(
             num_scalar_prefetch=3,
             grid=(n_shards, n_blocks, n_queries),
             in_specs=[block] * 5,
-            out_specs=[out_spec, out_spec],
+            out_specs=[_SMEM_OUT, _SMEM_OUT],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((n_shards, n_blocks, n_queries), jnp.int32),
-            jax.ShapeDtypeStruct((n_shards, n_blocks, n_queries), jnp.int32),
-        ],
+        out_shape=[_query_vector(n_queries), _query_vector(n_queries)],
         interpret=interpret,
     )(qparams, start_pages, blocks, pred0, pred1, agg, begin_ts, end_ts)
-    return (
-        jnp.sum(sums, axis=(0, 1), dtype=jnp.int32),
-        jnp.sum(cnts, axis=(0, 1), dtype=jnp.int32),
-    )
+    return sums, cnts
 
 
 # ---------------------------------------------------------------------------
@@ -399,14 +403,14 @@ def sharded_batched_filter_agg(
 # state, not query state, so the operand is (S, W) -- shared by every
 # query in the batch -- and the wrapper derives each shard's
 # [first, last] *live* block window (blocks holding at least one
-# uncovered real page) host^Wdevice-side before launch:
+# uncovered real page) on the device before launch:
 #
 # * whole blocks outside the window skip pre-DMA exactly like the
 #   prefix blocks of the start_pages kernel (the index map clamps into
 #   the window, so skipped steps revisit a resident block);
-# * inside the window, a static per-page unrolled bit test masks
-#   covered pages off (block_pages is a compile-time constant, so the
-#   unroll is exact and the word loads are SMEM scalar reads);
+# * inside the window, a vector bit test masks covered pages off: one
+#   SMEM scalar load per coverage word the block spans (a static count,
+#   since block_pages is a compile-time constant), shifted per row;
 # * an all-covered shard encodes the empty window [1, 0]: no block
 #   satisfies first <= blk <= last, and the index-map clamp still
 #   lands in-bounds at block 0.
@@ -450,10 +454,10 @@ def _masked_sharded_kernel(
     first_page = blk * block_pages
     live = (blk >= blocks_ref[s, 0]) & (blk <= blocks_ref[s, 1])
 
-    @pl.when(jnp.logical_not(live))
-    def _skip():
-        sum_ref[0, 0, 0] = jnp.int32(0)
-        cnt_ref[0, 0, 0] = jnp.int32(0)
+    @pl.when((s == 0) & (blk == 0))
+    def _init():
+        sum_ref[q] = jnp.int32(0)
+        cnt_ref[q] = jnp.int32(0)
 
     @pl.when(live)
     def _run():
@@ -464,19 +468,23 @@ def _masked_sharded_kernel(
         ets = end_ref[...]
         mask = (p0 >= lo0) & (p0 <= hi0) & (p1 >= lo1) & (p1 <= hi1)
         mask &= (bts <= ts) & (ts < ets)
-        # Static unroll over the block's pages: page first_page+j's
-        # built bit via one SMEM word load + arithmetic shift (the
-        # sign bit carries page 31 of each word; ``>> 31 & 1`` still
-        # extracts it exactly).
-        bits = []
-        for j in range(block_pages):
-            p = first_page + j
-            w = words_ref[s, p // 32]
-            bits.append((w >> (p % 32)) & 1)
-        covered = jnp.stack(bits).reshape(1, block_pages, 1)
+        # Each page's built bit, as a vector: one SMEM word load per
+        # coverage word the block spans, shifted by each row's bit
+        # position (an arithmetic shift: the sign bit carries page 31
+        # of a word, and ``>> 31 & 1`` still extracts it exactly).
+        pages = first_page + jax.lax.broadcasted_iota(jnp.int32, p0.shape, 1)
+        word_of = pages >> 5
+        covered = jnp.zeros(p0.shape, jnp.int32)
+        w0 = first_page >> 5
+        n_words = words_ref.shape[1]
+        for k in range(_words_spanned(block_pages)):
+            w = w0 + k
+            word = words_ref[s, jnp.minimum(w, n_words - 1)]
+            hit = (word_of == w) & (w < n_words)
+            covered = jnp.where(hit, (word >> (pages & 31)) & 1, covered)
         mask &= covered == 0
-        sum_ref[0, 0, 0] = jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
-        cnt_ref[0, 0, 0] = jnp.sum(mask, dtype=jnp.int32)
+        sum_ref[q] += jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
+        cnt_ref[q] += jnp.sum(mask, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
@@ -546,9 +554,6 @@ def sharded_batched_filter_agg_masked(
         return (s, jnp.clip(i, bi[s, 0], bi[s, 1]), 0)
 
     block = pl.BlockSpec((1, block_pages, page_size), _imap)
-    out_spec = pl.BlockSpec(
-        (1, 1, 1), lambda s, i, q, qp, wd, bi: (s, i, q)
-    )
     kernel = functools.partial(
         _masked_sharded_kernel, block_pages=block_pages
     )
@@ -558,15 +563,9 @@ def sharded_batched_filter_agg_masked(
             num_scalar_prefetch=3,
             grid=(n_shards, n_blocks, n_queries),
             in_specs=[block] * 5,
-            out_specs=[out_spec, out_spec],
+            out_specs=[_SMEM_OUT, _SMEM_OUT],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((n_shards, n_blocks, n_queries), jnp.int32),
-            jax.ShapeDtypeStruct((n_shards, n_blocks, n_queries), jnp.int32),
-        ],
+        out_shape=[_query_vector(n_queries), _query_vector(n_queries)],
         interpret=interpret,
     )(qparams, words, blocks, pred0, pred1, agg, begin_ts, end_ts)
-    return (
-        jnp.sum(sums, axis=(0, 1), dtype=jnp.int32),
-        jnp.sum(cnts, axis=(0, 1), dtype=jnp.int32),
-    )
+    return sums, cnts

@@ -20,9 +20,10 @@ TPU-native adaptation re-blocks it for the memory hierarchy:
   max(rho_m, rho_i + 1).  Scalar prefetch means the skip is decided
   before the DMA is issued, so skipped blocks cost neither bandwidth
   nor compute.
-* Partial (sum, count) per grid step land in a (grid,) x 2 output that
-  the wrapper reduces; accumulation stays int32 (the engine's
-  documented wraparound semantics).
+* (sum, count) accumulate across grid steps in two whole-array SMEM
+  scalars (a per-step scalar output block would break the (8, 128)
+  tiling rule); accumulation stays int32 (the engine's documented
+  wraparound semantics).
 
 MVCC visibility (begin_ts <= ts < end_ts) is fused into the predicate,
 so the kernel implements the full semantics of the engine's visible
@@ -68,6 +69,11 @@ def _filter_agg_kernel(
 
     first_page = pid * block_pages
 
+    @pl.when(pid == 0)
+    def _init():
+        sum_ref[0] = jnp.int32(0)
+        cnt_ref[0] = jnp.int32(0)
+
     def body():
         p0 = pred0_ref[...]
         p1 = pred1_ref[...]
@@ -80,21 +86,12 @@ def _filter_agg_kernel(
             # Per-page mask inside a block that straddles start_page.
             rows = jax.lax.broadcasted_iota(jnp.int32, p0.shape, 0)
             mask &= (first_page + rows) >= start_page
-        sum_ref[0] = jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
-        cnt_ref[0] = jnp.sum(mask, dtype=jnp.int32)
+        sum_ref[0] += jnp.sum(jnp.where(mask, ag, 0), dtype=jnp.int32)
+        cnt_ref[0] += jnp.sum(mask, dtype=jnp.int32)
 
     if use_start_page:
-        # Blocks entirely inside the indexed prefix are skipped before
-        # any compute; their outputs are zeroed.
-        @pl.when(first_page + block_pages <= start_page)
-        def _skip():
-            sum_ref[0] = jnp.int32(0)
-            cnt_ref[0] = jnp.int32(0)
-
-        @pl.when(first_page + block_pages > start_page)
-        def _run():
-            body()
-
+        # Blocks entirely inside the indexed prefix add nothing.
+        pl.when(first_page + block_pages > start_page)(body)
     else:
         body()
 
@@ -151,8 +148,8 @@ def filter_agg(
     # index_map receives (*grid_indices, *scalar_prefetch_refs).  The
     # hybrid variant clamps the block coordinate up to the first block
     # the scan needs: the skipped prefix revisits that resident block,
-    # so its DMAs are elided (the pre-DMA skip); pl.when still zeroes
-    # the prefix outputs.
+    # so its DMAs are elided (the pre-DMA skip); pl.when keeps the
+    # prefix out of the sums.
     if use_start:
 
         def _imap(i, s):
@@ -162,7 +159,7 @@ def filter_agg(
         block = pl.BlockSpec((block_pages, page_size), _imap)
     else:
         block = pl.BlockSpec((block_pages, page_size), lambda i, s: (i, 0))
-    out_spec = pl.BlockSpec((1,), lambda i, s: (i,))
+    out_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel = functools.partial(
         _filter_agg_kernel,
         block_pages=block_pages,
@@ -177,9 +174,9 @@ def filter_agg(
             out_specs=[out_spec, out_spec],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((grid,), jnp.int32),
-            jax.ShapeDtypeStruct((grid,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         interpret=interpret,
     )(scalars, pred0, pred1, agg, begin_ts, end_ts)
-    return jnp.sum(sums, dtype=jnp.int32), jnp.sum(cnts, dtype=jnp.int32)
+    return sums[0], cnts[0]

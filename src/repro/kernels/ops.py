@@ -5,13 +5,17 @@
 kernels' column-plane interface and pick hardware-aligned block shapes;
 ``scan_table_batched`` is the multi-query form and
 ``scan_shards_batched`` the fused multi-shard multi-query form over a
-stacked shard pytree (``core.table.stacked_shards``).  On this CPU
-container the kernels run in interpret mode by default; on TPU pass
-``interpret=False`` (the default flips via ``repro.kernels.INTERPRET``).
+stacked shard pytree (``core.table.stacked_shards``).
+
+Every wrapper's ``interpret=None`` resolves through ``interpret_mode``:
+the Pallas interpreter runs exactly when JAX's default backend is the
+CPU (the test suite), and a TPU backend always gets the compiled
+kernel -- never the interpreter on the chip.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import batched_filter_agg as _bfa
@@ -20,8 +24,10 @@ from repro.kernels import filter_agg as _fa
 I32_MIN = _fa.I32_MIN
 I32_MAX = _fa.I32_MAX
 
-# Flip to False on real TPU deployments.
-INTERPRET = True
+def interpret_mode() -> bool:
+    """Run the Pallas kernels in interpret mode?  True only when JAX's
+    default backend is the CPU, which has no Pallas TPU lowering."""
+    return jax.default_backend() == "cpu"
 
 
 def _pick_block_pages(n_pages: int) -> int:
@@ -82,7 +88,7 @@ def scan_table(table, attrs, los, his, ts, agg_attr, interpret=None):
     ``table`` is a repro.core.table.Table; ``attrs`` constrains 1 or 2
     columns with inclusive bounds los/his.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     pred0, pred1, lo0, hi0, lo1, hi1 = _single_bounds(table, attrs, los, his)
     agg = table.data[:, :, agg_attr]
     return _fa.filter_agg(
@@ -107,7 +113,7 @@ def scan_table_hybrid(
     """The hybrid scan's table-scan suffix: pages >= start_page only.
     Blocks fully inside the indexed prefix are skipped pre-DMA via the
     scalar-prefetched ``start_page``."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     pred0, pred1, lo0, hi0, lo1, hi1 = _single_bounds(table, attrs, los, his)
     agg = table.data[:, :, agg_attr]
     return _fa.filter_agg(
@@ -144,7 +150,7 @@ def scan_table_batched(
             f"kernel scans support 1 or 2 predicate attributes, "
             f"got {attrs!r}"
         )
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     n_queries = jnp.asarray(los).shape[0]
     pred0, pred1, los0, his0, los1, his1 = _batch_bounds(
         table.data, attrs, los, his
@@ -188,7 +194,7 @@ def scan_shards_batched(
             f"kernel scans support 1 or 2 predicate attributes, "
             f"got {attrs!r}"
         )
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     t = stacked.table
     pred0, pred1, los0, his0, los1, his1 = _batch_bounds(
         t.data, attrs, los, his
@@ -227,7 +233,7 @@ def scan_table_batched_masked(
             f"kernel scans support 1 or 2 predicate attributes, "
             f"got {attrs!r}"
         )
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     pred0, pred1, los0, his0, los1, his1 = _batch_bounds(
         table.data, attrs, los, his
     )
@@ -263,7 +269,7 @@ def scan_shards_batched_masked(
             f"kernel scans support 1 or 2 predicate attributes, "
             f"got {attrs!r}"
         )
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     t = stacked.table
     pred0, pred1, los0, his0, los1, his1 = _batch_bounds(
         t.data, attrs, los, his

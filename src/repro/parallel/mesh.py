@@ -17,9 +17,6 @@ touches ``jax.local_devices`` directly:
   second query-batch axis for 2-D read bursts), cached per process.
 * ``stacked_specs`` / ``batch_spec`` -- PartitionSpec prefixes for the
   stacked table/index pytrees and the per-query bound vectors.
-* ``shard_map``        -- version-compat shim (jax >= 0.6 spells it
-  ``jax.shard_map``; older releases only have
-  ``jax.experimental.shard_map.shard_map``).
 
 The model-parallel mesh for the learned components lives in
 ``launch.mesh``; this one is deliberately separate -- the storage
@@ -38,29 +35,6 @@ from jax.sharding import PartitionSpec as P
 
 SHARD_AXIS = "shard"
 QUERY_AXIS = "qbatch"
-
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Full-manual shard_map across jax versions (cf. the
-    partial-manual twin in ``train.steps``)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=False,
-            )
-        except TypeError:  # pre-check_vma spelling
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-            )
-    from jax.experimental.shard_map import shard_map as _shmap
-
-    return _shmap(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 
 @functools.lru_cache(maxsize=32)

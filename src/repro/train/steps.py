@@ -27,20 +27,11 @@ from repro.train.optimizer import (AdamWState, adamw_init, adamw_update,
 
 
 def _partial_shard_map(f, mesh, manual_axes, in_specs, out_specs):
-    """Partial-manual shard_map across jax versions: ``manual_axes``
-    are manual, every other mesh axis stays in auto (pjit) mode.
-    jax >= 0.6 exposes ``jax.shard_map(axis_names=...)``; older
-    releases spell it ``jax.experimental.shard_map.shard_map(auto=...)``.
-    """
-    manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=manual,
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shmap
-    auto = frozenset(mesh.shape) - manual
-    return _shmap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, auto=auto)
+    """Partial-manual shard_map: ``manual_axes`` are manual, every
+    other mesh axis stays in auto (pjit) mode."""
+    return jax.shard_map(f, mesh=mesh, axis_names=frozenset(manual_axes),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 class TrainState(NamedTuple):

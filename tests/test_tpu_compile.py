@@ -1,0 +1,188 @@
+"""The scan kernels compile for a TPU v5e at the paper's table widths.
+
+Interpret mode (every other kernel test) cannot see what the TPU's
+compiler refuses: block shapes that break the (8, 128) tiling rule,
+more VMEM than a kernel may use, a program that does not fit the
+device.  These tests lower each Pallas scan kernel with
+``interpret=False`` and compile it for a *described* ``v5e:2x2``
+topology -- no chip attached -- at TUNER narrow's 10M-row widths, with
+the block size a chip launch would use (``tpu_block_pages``).  A
+compiled program holding ``tpu_custom_call`` is a Mosaic kernel.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library, and the
+worker that runs this file keeps it until it exits.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.compile_cache import compile_cache_off
+from repro.core import engine
+from repro.core.table import StackedShards, Table
+from repro.kernels import batched_filter_agg as bfa
+from repro.kernels import filter_agg as fa
+from repro.kernels import ops
+from repro.parallel.mesh import SHARD_AXIS
+
+# TUNER narrow at the paper's scale: 10M rows, headroom 1.5, 256-row
+# pages -> 58594 pages; 4 round-robin shards hold 14649 pages each.
+N_PAGES = 58594
+PAGE_SIZE = 256
+N_ATTRS = 21
+BATCH = 16
+SHARDS = 4
+LOCAL_PAGES = -(-N_PAGES // SHARDS)
+BLOCK_PAGES = bfa.tpu_block_pages(N_PAGES, PAGE_SIZE)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off.
+    with compile_cache_off():
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return Mesh(np.array(topo.devices), (SHARD_AXIS,))
+
+
+def _i32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _assert_mosaic(lowered):
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_block_pages_for_the_paper_table():
+    # 5 streamed int32 planes of 256 lanes, double-buffered in 4 MiB.
+    assert BLOCK_PAGES == 256
+
+
+@pytest.mark.parametrize("hybrid", [False, True], ids=["full", "hybrid"])
+def test_filter_agg_compiles(one_chip, hybrid):
+    plane = _i32((N_PAGES, PAGE_SIZE), one_chip)
+    scalar = _i32((), one_chip)
+    start = scalar if hybrid else None
+    _assert_mosaic(
+        fa.filter_agg.lower(
+            *[plane] * 5,
+            *[scalar] * 5,
+            start,
+            block_pages=BLOCK_PAGES,
+            interpret=False,
+        )
+    )
+
+
+def test_batched_filter_agg_compiles(one_chip):
+    plane = _i32((N_PAGES, PAGE_SIZE), one_chip)
+    per_query = _i32((BATCH,), one_chip)
+    compiled = _assert_mosaic(
+        bfa.batched_filter_agg.lower(
+            *[plane] * 5,
+            *[per_query] * 6,
+            block_pages=BLOCK_PAGES,
+            interpret=False,
+        )
+    )
+    # The outputs are the (B,) sums and counts, nothing per block.
+    assert compiled.memory_analysis().output_size_in_bytes < 4096
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["starts", "masked"])
+def test_sharded_batched_filter_agg_compiles(one_chip, masked):
+    plane = _i32((SHARDS, LOCAL_PAGES, PAGE_SIZE), one_chip)
+    per_query = _i32((BATCH,), one_chip)
+    local_pages = _i32((SHARDS,), one_chip)
+    if masked:
+        kernel = bfa.sharded_batched_filter_agg_masked
+        table = _i32((SHARDS, -(-LOCAL_PAGES // 32)), one_chip)
+    else:
+        kernel = bfa.sharded_batched_filter_agg
+        table = _i32((SHARDS, BATCH), one_chip)
+    _assert_mosaic(
+        kernel.lower(
+            *[plane] * 5,
+            *[per_query] * 5,
+            table,
+            local_pages,
+            block_pages=BLOCK_PAGES,
+            interpret=False,
+        )
+    )
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["starts", "masked"])
+def test_mesh_kernel_suffix_compiles_on_four_chips(mesh, masked):
+    """The per-local-shard kernel suffix inside the engine's
+    ``shard_map`` body, one shard per chip of the 2x2 mesh.  This
+    process's backend is the CPU, so the test steers ``ops`` off
+    interpret mode the way a TPU backend would."""
+    shard = NamedSharding(mesh, P(SHARD_AXIS))
+    rep = NamedSharding(mesh, P())
+    planes = (SHARDS, LOCAL_PAGES, PAGE_SIZE)
+    stk = StackedShards(
+        table=Table(
+            _i32(planes + (N_ATTRS,), shard),
+            _i32(planes, shard),
+            _i32(planes, shard),
+            _i32((SHARDS,), shard),
+        ),
+        shard_ids=_i32((SHARDS,), shard),
+        local_pages=_i32((SHARDS,), shard),
+    )
+    bounds = _i32((BATCH, 1), rep)
+    tss = _i32((BATCH,), rep)
+    if masked:
+        suffix = engine._mesh_kernel_suffix_masked
+        extra = _i32((SHARDS, -(-LOCAL_PAGES // 32)), shard)
+    else:
+        suffix = engine._mesh_kernel_suffix
+        extra = _i32((SHARDS, BATCH), shard)
+
+    def body(stk, los, his, tss, extra):
+        sums, cnts = suffix(stk, (3,), los, his, tss, 2, extra)
+        return (
+            jax.lax.psum(sums, SHARD_AXIS),
+            jax.lax.psum(cnts, SHARD_AXIS),
+        )
+
+    program = jax.jit(
+        jax.shard_map(
+            body,
+            mesh=mesh,
+            in_specs=(P(SHARD_AXIS), P(), P(), P(), P(SHARD_AXIS)),
+            out_specs=(P(), P()),
+            check_vma=False,
+        )
+    )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "interpret_mode", lambda: False)
+        compiled = _assert_mosaic(program.lower(stk, bounds, bounds, tss, extra))
+    assert "all-reduce" in compiled.as_text()
