@@ -24,7 +24,6 @@ shard counts (tests/test_sharded_engine.py).
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
@@ -71,6 +70,7 @@ from repro.core.table import (
     unshard_table,
     update_rows,
 )
+from repro.obs import host_read, span
 
 
 @dataclass
@@ -107,7 +107,6 @@ class Query:
 class ExecStats:
     cost_units: float  # tuple-touch units (simulated work)
     latency_ms: float  # simulated latency
-    wall_s: float  # measured wall time of the jitted ops
     used_index: bool
     agg_sum: int = 0
     count: int = 0
@@ -330,88 +329,110 @@ class Database:
     # Execution
     # ------------------------------------------------------------------
     def execute(self, q: Query, observe: bool = True) -> ExecStats:
-        if q.kind == "scan":
-            stats = self._exec_scan(q)
-        elif q.kind == "update":
-            stats = self._exec_update(q)
-        elif q.kind == "insert":
-            stats = self._exec_insert(q)
-        else:
-            raise ValueError(q.kind)
-        # Non-burst drain point: single-dispatch workloads feed the
-        # concurrent build lane exactly like the batched path does
-        # between group dispatches (no-op unless overlap scheduling
-        # installed a hook; the statement's timed region is closed).
-        self.engine.dispatch_complete()
-        self.clock_ms += stats.latency_ms
-        if observe:
-            n_rows = int(self.tables[q.table].n_rows)
-            self.monitor.observe(
-                QueryRecord(
-                    kind=q.kind,
-                    table=q.table,
-                    pred_attrs=tuple(q.attrs),
-                    accessed_attrs=q.accessed_attrs,
-                    selectivity=(
-                        stats.count / max(n_rows, 1)
-                        if q.kind == "scan"
-                        else stats.rows_modified / max(n_rows, 1)
-                    ),
-                    tuples_scanned=int(stats.cost_units),
-                    used_index=stats.used_index,
-                    rows_modified=stats.rows_modified,
-                    ts_ms=self.clock_ms,
-                    template=q.template,
-                    shard_pages=stats.shard_pages,
-                    pred_ranges=tuple(zip(q.attrs, q.los, q.his)),
-                )
-            )
-            if q.join_table is not None:
-                # The inner side of an equi-join is an indexable access
-                # path too (HIGH-S benefits from join-attribute indexes).
-                n_inner = int(self.tables[q.join_table].n_rows)
-                self.monitor.observe(
-                    QueryRecord(
-                        kind="scan",
-                        table=q.join_table,
-                        pred_attrs=(q.join_inner_attr,),
-                        selectivity=min(stats.count / max(n_inner, 1), 1.0),
-                        tuples_scanned=n_inner,
-                        used_index=stats.used_index,
-                        rows_modified=0,
-                        ts_ms=self.clock_ms,
-                        template=q.template + ":join",
+        with span("repro.execute", kind=q.kind):
+            if q.kind == "scan":
+                stats = self._exec_scan(q)
+            elif q.kind == "update":
+                stats = self._exec_update(q)
+            elif q.kind == "insert":
+                stats = self._exec_insert(q)
+            else:
+                raise ValueError(q.kind)
+            # Non-burst drain point: single-dispatch workloads feed the
+            # concurrent build lane exactly like the batched path does
+            # between group dispatches (no-op unless overlap scheduling
+            # installed a hook; the statement's timed region is closed).
+            self.engine.dispatch_complete()
+            self.clock_ms += stats.latency_ms
+            if observe:
+                n_rows = int(
+                    host_read(
+                        self.tables[q.table].n_rows, "executor.execute.n_rows"
                     )
                 )
-        return stats
+                self.monitor.observe(
+                    QueryRecord(
+                        kind=q.kind,
+                        table=q.table,
+                        pred_attrs=tuple(q.attrs),
+                        accessed_attrs=q.accessed_attrs,
+                        selectivity=(
+                            stats.count / max(n_rows, 1)
+                            if q.kind == "scan"
+                            else stats.rows_modified / max(n_rows, 1)
+                        ),
+                        tuples_scanned=int(stats.cost_units),
+                        used_index=stats.used_index,
+                        rows_modified=stats.rows_modified,
+                        ts_ms=self.clock_ms,
+                        template=q.template,
+                        shard_pages=stats.shard_pages,
+                        pred_ranges=tuple(zip(q.attrs, q.los, q.his)),
+                    )
+                )
+                if q.join_table is not None:
+                    # The inner side of an equi-join is an indexable access
+                    # path too (HIGH-S benefits from join-attribute indexes).
+                    n_inner = int(self.tables[q.join_table].n_rows)
+                    self.monitor.observe(
+                        QueryRecord(
+                            kind="scan",
+                            table=q.join_table,
+                            pred_attrs=(q.join_inner_attr,),
+                            selectivity=min(
+                                stats.count / max(n_inner, 1), 1.0
+                            ),
+                            tuples_scanned=n_inner,
+                            used_index=stats.used_index,
+                            rows_modified=0,
+                            ts_ms=self.clock_ms,
+                            template=q.template + ":join",
+                        )
+                    )
+            return stats
 
     def _exec_scan(self, q: Query) -> ExecStats:
         t = self.tables[q.table]
         layout = self.layouts[q.table]
-        los = jnp.asarray(q.los, jnp.int32)
-        his = jnp.asarray(q.his, jnp.int32)
         plan = self.planner.plan_scan(q)
         bi = plan.index
 
-        t0 = time.perf_counter()
-        r = self.engine.scan(
-            t, plan, tuple(q.attrs), los, his, self.clock_ms_i32(), q.agg_attr
-        )
-        wall = time.perf_counter() - t0
+        with span(
+            "repro.executor.dispatch",
+            path=plan.path,
+            index=bi.desc.name if bi is not None else "",
+            queries=1,
+        ) as sp:
+            los = jnp.asarray(q.los, jnp.int32)
+            his = jnp.asarray(q.his, jnp.int32)
+            r = self.engine.scan(
+                t,
+                plan,
+                tuple(q.attrs),
+                los,
+                his,
+                self.clock_ms_i32(),
+                q.agg_attr,
+            )
+            sp.set_metadata(tier=self.engine.last_tier or "")
 
         if plan.path == "table":
             start_page, entries = 0, 0.0
         elif plan.path in ("hybrid", "hybrid_ps", "hybrid_masked"):
-            start_page = int(r.start_page)
-            entries = float(int(r.entries_probed))
+            start_page = int(host_read(r.start_page, "executor.scan.start"))
+            entries = float(
+                int(host_read(r.entries_probed, "executor.scan.entries"))
+            )
         else:  # pure index scan: no table pages touched
             start_page = t.n_pages
-            entries = float(int(r.entries_probed))
+            entries = float(
+                int(host_read(r.entries_probed, "executor.scan.entries"))
+            )
         cost = scan_cost(
             layout,
             q.accessed_attrs,
             t.page_size,
-            int(r.pages_scanned),
+            int(host_read(r.pages_scanned, "executor.scan.pages")),
             entries,
             start_page,
         )
@@ -421,7 +442,7 @@ class Database:
         if used:
             bi.last_used_ms = self.clock_ms
 
-        count = int(r.count)
+        count = int(host_read(r.count, "executor.scan.count"))
         if q.join_table is not None:
             count, join_cost, join_used = self._exec_join(q, r)
             cost += join_cost
@@ -438,9 +459,8 @@ class Database:
         return ExecStats(
             cost_units=cost,
             latency_ms=lat_ms,
-            wall_s=wall,
             used_index=used,
-            agg_sum=int(r.agg_sum),
+            agg_sum=int(host_read(r.agg_sum, "executor.scan.sum")),
             count=count,
             populate_units=populate,
             shard_pages=self._shard_pages_of(t, plan),
@@ -567,13 +587,14 @@ class Database:
                 self._exec_scan_burst(pending, out, observe, use_kernel)
                 pending.clear()
 
-        for i, q in enumerate(queries):
-            if q.kind == "scan" and q.join_table is None:
-                pending.append((i, q))
-            else:
-                flush()
-                out[i] = self.execute(q, observe=observe)
-        flush()
+        with span("repro.execute_batch", statements=len(queries)):
+            for i, q in enumerate(queries):
+                if q.kind == "scan" and q.join_table is None:
+                    pending.append((i, q))
+                else:
+                    flush()
+                    out[i] = self.execute(q, observe=observe)
+            flush()
         return out
 
     def _exec_scan_burst(
@@ -598,39 +619,47 @@ class Database:
             # Run each group in one dispatch (one fan-out per shard when
             # the table is sharded); gather per-position raw rows.
             ts = self.clock_ms_i32()
-            # pos -> (sum, count, pages, entries, start_page,
-            # wall_share, tier)
+            # pos -> (sum, count, pages, entries, start_page, tier)
             raw: Dict[int, tuple] = {}
             # pos -> (retries, straggler-share ms).  One fault draw
             # per GROUP dispatch (the dispatch is the fault domain);
             # its straggler latency is shared evenly across members.
             fault_by_pos: Dict[int, tuple] = {}
             for group_key, members in groups.items():
-                table_name, attrs, agg_attr, _path, _idx = group_key
+                table_name, attrs, agg_attr, path, idx = group_key
                 t = self.tables[table_name]
-                los = jnp.asarray([q.los for _, q, _ in members], jnp.int32)
-                his = jnp.asarray([q.his for _, q, _ in members], jnp.int32)
-                tss = jnp.full((len(members),), ts, jnp.int32)
                 plan = members[0][2]
-                t0 = time.perf_counter()
-                r = self.engine.scan_batch(
-                    t,
-                    plan.path,
-                    plan.index_state,
-                    plan.key_attrs,
-                    attrs,
-                    los,
-                    his,
-                    tss,
-                    agg_attr,
-                    use_kernel=use_kernel,
-                    coverage=plan.pinned_coverage,
-                )
-                wall = time.perf_counter() - t0
-                tier = self.engine.last_tier or ""
+                with span(
+                    "repro.executor.dispatch",
+                    path=path,
+                    index=idx or "",
+                    queries=len(members),
+                ) as sp:
+                    los = jnp.asarray(
+                        [q.los for _, q, _ in members], jnp.int32
+                    )
+                    his = jnp.asarray(
+                        [q.his for _, q, _ in members], jnp.int32
+                    )
+                    tss = jnp.full((len(members),), ts, jnp.int32)
+                    r = self.engine.scan_batch(
+                        t,
+                        plan.path,
+                        plan.index_state,
+                        plan.key_attrs,
+                        attrs,
+                        los,
+                        his,
+                        tss,
+                        agg_attr,
+                        use_kernel=use_kernel,
+                        coverage=plan.pinned_coverage,
+                    )
+                    tier = self.engine.last_tier or ""
+                    sp.set_metadata(tier=tier)
                 # Drain point between this group's dispatch and the
-                # next (outside the timed region: quantum work must
-                # not be charged to the burst's measured wall time).
+                # next, outside its span: quantum work is the build
+                # lane's, not the dispatch's.
                 self.engine.dispatch_complete()
                 if self.fault_injector is not None:
                     retries, extra_ms = self.fault_injector.scan_fault()
@@ -638,11 +667,11 @@ class Database:
                         share = extra_ms / len(members)
                         for pos, _q, _plan in members:
                             fault_by_pos[pos] = (retries, share)
-                agg_sums = np.asarray(r.agg_sum)
-                counts = np.asarray(r.count)
-                pages = np.asarray(r.pages_scanned)
-                entries = np.asarray(r.entries_probed)
-                starts = np.asarray(r.start_page)
+                agg_sums = host_read(r.agg_sum, "executor.burst.sum")
+                counts = host_read(r.count, "executor.burst.count")
+                pages = host_read(r.pages_scanned, "executor.burst.pages")
+                entries = host_read(r.entries_probed, "executor.burst.entries")
+                starts = host_read(r.start_page, "executor.burst.start")
                 for k, (pos, _q, _plan) in enumerate(members):
                     raw[pos] = (
                         int(agg_sums[k]),
@@ -650,7 +679,6 @@ class Database:
                         int(pages[k]),
                         int(entries[k]),
                         int(starts[k]),
-                        wall / len(members),
                         tier,
                     )
         finally:
@@ -663,7 +691,7 @@ class Database:
         }
         for pos, q in pending:
             rec = raw[pos]
-            agg_sum, count, n_pages, n_entries, start_page, wall, tier = rec
+            agg_sum, count, n_pages, n_entries, start_page, tier = rec
             t = self.tables[q.table]
             layout = self.layouts[q.table]
             plan_q = plan_by_pos[pos]
@@ -692,7 +720,6 @@ class Database:
             stats = ExecStats(
                 cost_units=cost,
                 latency_ms=lat_ms,
-                wall_s=wall,
                 used_index=used,
                 agg_sum=agg_sum,
                 count=count,
@@ -702,7 +729,7 @@ class Database:
             )
             self.clock_ms += stats.latency_ms
             if observe:
-                n_rows = int(t.n_rows)
+                n_rows = int(host_read(t.n_rows, "executor.account.n_rows"))
                 self.monitor.observe(
                     QueryRecord(
                         kind="scan",
@@ -787,36 +814,35 @@ class Database:
     def _exec_update(self, q: Query) -> ExecStats:
         t = self.tables[q.table]
         layout = self.layouts[q.table]
-        los = jnp.asarray(q.los, jnp.int32)
-        his = jnp.asarray(q.his, jnp.int32)
         mutate = (
             sharded_update_rows if isinstance(t, ShardedTable) else update_rows
         )
-        t0 = time.perf_counter()
-        new_t, n_upd = mutate(
-            t,
-            tuple(q.attrs),
-            los,
-            his,
-            tuple(q.set_attrs),
-            jnp.asarray(q.set_vals, jnp.int32),
-            self.clock_ms_i32(),
-            max_new=self.update_cap,
-        )
-        wall = time.perf_counter() - t0
+        with span("repro.executor.dispatch", path="update", queries=1):
+            los = jnp.asarray(q.los, jnp.int32)
+            his = jnp.asarray(q.his, jnp.int32)
+            new_t, n_upd = mutate(
+                t,
+                tuple(q.attrs),
+                los,
+                his,
+                tuple(q.set_attrs),
+                jnp.asarray(q.set_vals, jnp.int32),
+                self.clock_ms_i32(),
+                max_new=self.update_cap,
+            )
         self.tables[q.table] = new_t
-        n_upd = int(n_upd)
+        n_upd = int(host_read(n_upd, "executor.update.rows"))
         # Row lookup: table scan unless an index matches the predicate.
         bi = self._choose_index(q)
         if bi is not None and bi.scheme in ("vap",):
             frac = bi.built_fraction(t)
-            lookup = (
-                1.0 - frac
-            ) * float(int(t.n_rows)) + cm.INDEX_PROBE_COST * n_upd
+            n_rows = int(host_read(t.n_rows, "executor.update.n_rows"))
+            lookup = (1.0 - frac) * float(n_rows) + cm.INDEX_PROBE_COST * n_upd
             bi.last_used_ms = self.clock_ms
         else:
             width = scan_width_factor(layout, tuple(q.attrs), 0)
-            lookup = float(int(t.n_rows)) * (width / layout.n_attrs)
+            n_rows = int(host_read(t.n_rows, "executor.update.n_rows"))
+            lookup = float(n_rows) * (width / layout.n_attrs)
         maint = cm.tau_maintenance(n_upd) * max(
             len(self.indexes_on(q.table)), 0
         )
@@ -825,7 +851,6 @@ class Database:
         return ExecStats(
             cost_units=cost,
             latency_ms=cost * self.time_per_unit_ms,
-            wall_s=wall,
             used_index=bi is not None,
             rows_modified=n_upd,
         )
@@ -836,7 +861,6 @@ class Database:
         mutate = (
             sharded_insert_rows if isinstance(t, ShardedTable) else insert_rows
         )
-        t0 = time.perf_counter()
         new_t = mutate(
             t,
             jnp.asarray(rows),
@@ -844,7 +868,6 @@ class Database:
             rows.shape[0],
             max_new=rows.shape[0],
         )
-        wall = time.perf_counter() - t0
         self.tables[q.table] = new_t
         n = rows.shape[0]
         maint = cm.tau_maintenance(n) * max(len(self.indexes_on(q.table)), 0)
@@ -853,7 +876,6 @@ class Database:
         return ExecStats(
             cost_units=cost,
             latency_ms=cost * self.time_per_unit_ms,
-            wall_s=wall,
             used_index=False,
             rows_modified=n,
         )
@@ -894,18 +916,35 @@ class Database:
         meaningful in bitmap mode (prefix builds cannot express it).
         """
         t = self.tables[bi.desc.table]
-        if bi.coverage is not None:
-            return self._coverage_build_step(bi, t, pages, shard, page_list)
+        with span(
+            "repro.build.apply", index=bi.desc.name, pages=int(pages)
+        ) as sp:
+            if bi.coverage is not None:
+                work = self._coverage_build_step(
+                    bi, t, pages, shard, page_list
+                )
+            else:
+                work = self._prefix_build_step(bi, t, pages, shard)
+            # work units are pages built x page size
+            sp.set_metadata(pages_built=int(work) // t.page_size)
+        return work
+
+    def _prefix_build_step(
+        self, bi: BuiltIndex, t, pages: int, shard: Optional[int]
+    ) -> float:
+        """Prefix-mode build quantum: advance the built watermark."""
         if shard is None:
             bi.vap, done = advance_build(bi.vap, t, bi.desc.key_attrs, pages)
-            full_pages = int(t.n_rows) // t.page_size
+            n_rows = host_read(t.n_rows, "executor.build_step.n_rows")
+            full_pages = int(n_rows) // t.page_size
         else:
             bi.vap, done = advance_build_shard(
                 bi.vap, t, bi.desc.key_attrs, shard, pages
             )
             self.pershard_built.add(bi.desc.name)
             full_pages = sum(shard_full_pages(t))
-        if int(bi.vap.built_pages) >= full_pages:
+        built = host_read(bi.vap.built_pages, "executor.build_step.built")
+        if int(built) >= full_pages:
             bi.complete = True
             bi.building = False
         return float(done * t.page_size)

@@ -72,6 +72,7 @@ import numpy as np
 
 from repro.core.table import (INF_TS, ShardedTable, Table, global_rids,
                               identity_lru_lookup)
+from repro.obs import host_read
 
 I32_MAX = jnp.int32(2**31 - 1)
 I32_MIN = jnp.int32(-(2**31))
@@ -566,20 +567,22 @@ def advance_build(state, table, key_attrs: tuple, pages: int):
     the build clamps at the table's full-page watermark (the unused
     budget is the caller's to carry over).
     """
-    before = int(state.built_pages)
+    before = int(host_read(state.built_pages, "index.advance_build.before"))
     if isinstance(state, ShardedIndex):
         state = sharded_build_pages_vap(state, table, key_attrs,
                                         pages_per_cycle=int(pages))
     else:
         state = build_pages_vap(state, table, key_attrs,
                                 pages_per_cycle=int(pages))
-    return state, int(state.built_pages) - before
+    after = host_read(state.built_pages, "index.advance_build.after")
+    return state, int(after) - before
 
 
 def build_pages_remaining(state, table) -> int:
     """Fully-populated pages not yet covered by the built prefix."""
-    full_pages = int(table.n_rows) // table.page_size
-    return max(full_pages - int(state.built_pages), 0)
+    n_rows = host_read(table.n_rows, "index.pages_remaining.n_rows")
+    built = host_read(state.built_pages, "index.pages_remaining.built")
+    return max(int(n_rows) // table.page_size - int(built), 0)
 
 
 # ---------------------------------------------------------------------------
@@ -625,11 +628,12 @@ def advance_build_shard(state: ShardedIndex, table: ShardedTable,
     exactly like ``advance_build``; the quantum clamps at the shard's
     own full-page watermark."""
     ix, t = state.shards[shard], table.shards[shard]
-    before = int(ix.built_pages)
+    before = int(host_read(ix.built_pages, "index.advance_build.before"))
     ix = build_pages_vap(ix, t, key_attrs, pages_per_cycle=int(pages))
     shards = list(state.shards)
     shards[shard] = ix
-    return ShardedIndex(tuple(shards)), int(ix.built_pages) - before
+    after = host_read(ix.built_pages, "index.advance_build.after")
+    return ShardedIndex(tuple(shards)), int(after) - before
 
 
 def split_build_pages(pages: int, quantum_pages: int | None):

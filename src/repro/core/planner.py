@@ -39,6 +39,7 @@ from repro.core.index import (
 )
 from repro.core.layout import LayoutState, scan_width_factor
 from repro.core.table import ShardedTable
+from repro.obs import host_read
 
 HYBRID_SELECTIVITY_CUTOFF = 0.20  # optimizer switches to table scan above
 
@@ -84,11 +85,13 @@ class IntervalUnion:
 def built_fraction_of(scheme: str, vap, vbp, table) -> float:
     """Built fraction from raw index state (shared by the live catalog
     record and the planner's frozen snapshot view)."""
+    n_rows = int(host_read(table.n_rows, "planner.built_fraction.n_rows"))
     if scheme in ("vap", "full"):
-        full_pages = max(int(table.n_rows) // table.page_size, 1)
-        return min(int(vap.built_pages) / full_pages, 1.0)
-    n = max(int(table.n_rows), 1)
-    return min(int(vbp_n_entries(vbp)) / n, 1.0)
+        full_pages = max(n_rows // table.page_size, 1)
+        built = host_read(vap.built_pages, "planner.built_fraction.built")
+        return min(int(built) / full_pages, 1.0)
+    entries = host_read(vbp_n_entries(vbp), "planner.built_fraction.entries")
+    return min(int(entries) / max(n_rows, 1), 1.0)
 
 
 @dataclass
@@ -118,7 +121,8 @@ class BuiltIndex:
 
     def built_fraction(self, table) -> float:
         if self.coverage is not None and self.scheme in ("vap", "full"):
-            full_pages = max(int(table.n_rows) // table.page_size, 1)
+            n_rows = host_read(table.n_rows, "planner.built_fraction.n_rows")
+            full_pages = max(int(n_rows) // table.page_size, 1)
             return min(self.coverage.count() / full_pages, 1.0)
         return built_fraction_of(self.scheme, self.vap, self.vbp, table)
 
@@ -131,8 +135,10 @@ class BuiltIndex:
                 self.coverage.count() * self.coverage.page_size
             )
         if self.scheme in ("vap", "full"):
-            return 12.0 * float(int(self.vap.n_entries))
-        return 12.0 * float(int(vbp_n_entries(self.vbp)))
+            entries = self.vap.n_entries
+        else:
+            entries = vbp_n_entries(self.vbp)
+        return 12.0 * float(int(host_read(entries, "planner.size.entries")))
 
 
 @dataclass(frozen=True)
@@ -319,10 +325,11 @@ class QueryPlanner:
         t = self.db.tables[q.table]
         layout = self.db.layouts[q.table]
         psz = t.page_size
-        n_rows = int(t.n_rows)
+        n_rows = int(host_read(t.n_rows, "planner.estimate.n_rows"))
         if isinstance(t, ShardedTable):
             used_pages = sum(
-                -(-int(s.n_rows) // psz) for s in t.shards
+                -(-int(host_read(s.n_rows, "planner.estimate.n_rows")) // psz)
+                for s in t.shards
             )
         else:
             used_pages = -(-n_rows // psz)
@@ -366,10 +373,13 @@ class QueryPlanner:
         """A bitmap that IS the prefix the index watermark claims (and
         has no stray entries beyond it) takes the legacy start_page
         paths bit for bit -- routing is a fast-path choice only."""
+        site = "planner.coverage_is_legacy.built"
         if isinstance(vap, ShardedIndex):
-            built = sum(int(ix.built_pages) for ix in vap.shards)
+            built = sum(
+                int(host_read(ix.built_pages, site)) for ix in vap.shards
+            )
         else:
-            built = int(vap.built_pages)
+            built = int(host_read(vap.built_pages, site))
         return cov.legacy_prefix_ok(built)
 
     def _pin_coverage(self, bi: BuiltIndex, cov):

@@ -61,6 +61,7 @@ from repro.core.index import (
     shard_remaining_pages,
 )
 from repro.core.table import ShardedTable
+from repro.obs import host_read, span
 
 
 @dataclass
@@ -161,6 +162,14 @@ class PredictiveTuner:
         records instead of being executed inline.  Accounting is
         unchanged: applying the quanta in order performs exactly the
         work the legacy monolithic cycle did."""
+        with span("repro.tuner.decide") as sp:
+            plan = self._decide(idle)
+            sp.set_metadata(
+                candidates=len(self.descs), quanta=len(plan.quanta)
+            )
+        return plan
+
+    def _decide(self, idle: bool) -> CyclePlan:
         db, cfg = self.db, self.cfg
         db.monitor.prune(db.clock_ms)
         shard_aware = bool(getattr(db, "shard_aware_tuning", False))
@@ -207,7 +216,7 @@ class PredictiveTuner:
         for name in names:
             desc = self.descs[name]
             t = db.tables[desc.table]
-            n_rows = int(t.n_rows)
+            n_rows = int(host_read(t.n_rows, "tuner.decide.n_rows"))
             o = cm.overall_utility(
                 desc,
                 scans.get(desc.table, ()),
@@ -352,7 +361,9 @@ class PredictiveTuner:
                     st, observed[name], cfg.alpha, cfg.beta, cfg.gamma
                 )
                 self.models[name] = st
-                self.forecasts[name] = float(hw.forecast(st, 1))
+                self.forecasts[name] = float(
+                    host_read(hw.forecast(st, 1), "tuner.decide.forecast")
+                )
         self.cycles += 1
         return CyclePlan(quanta=quanta)
 
