@@ -140,8 +140,8 @@ def run_pass(api, tables, workload, execution, compile_clock):
     Tuning cycles fire every 10 simulated seconds at the paper's 10M
     rows (the paper's SLOW frequency, Section V-B), scaled with the row
     count so a cut table still tunes as often per statement.  Each
-    build quantum re-sorts the whole index capacity (ROADMAP S6), so
-    the cycle count bounds the pass's time."""
+    build quantum merges its pages into the index in linear passes over
+    its capacity, so the cycle count bounds the pass's time."""
     db = api.Database(dict(tables), num_shards=execution.num_shards)
     rows = int(db.tables["narrow"].n_rows)
     tuner = api.PredictiveTuner(

@@ -8,6 +8,9 @@ device.  These tests lower each Pallas scan kernel with
 topology -- no chip attached -- at TUNER narrow's 10M-row widths, with
 the block size a chip launch would use (``tpu_block_pages``).  A
 compiled program holding ``tpu_custom_call`` is a Mosaic kernel.
+The VAP build program is compiled the same way, at the benchmark's
+narrow and wide table shapes, to read what its HLO sorts and how much
+temporary memory it takes.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and the
@@ -15,6 +18,7 @@ worker that runs this file keeps it until it exits.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.compile_cache import compile_cache_off
 from repro.core import engine
+from repro.core.index import AdHocIndex, _build_pages_vap
 from repro.core.table import StackedShards, Table
 from repro.kernels import batched_filter_agg as bfa
 from repro.kernels import filter_agg as fa
@@ -186,3 +191,37 @@ def test_mesh_kernel_suffix_compiles_on_four_chips(mesh, masked):
         mp.setattr(ops, "interpret_mode", lambda: False)
         compiled = _assert_mosaic(program.lower(stk, bounds, bounds, tss, extra))
     assert "all-reduce" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "n_pages, n_attrs",
+    [(N_PAGES, N_ATTRS), (35157, 201)],
+    ids=["narrow_10M", "wide_6M"],
+)
+def test_vap_build_merges_without_capacity_sort(one_chip, n_pages, n_attrs):
+    """A VAP build quantum at the benchmark's table shapes sorts only
+    its own 64-page chunk and slices the key plane out of the table:
+    no sort as long as the index, and no copy of the table."""
+    capacity = n_pages * PAGE_SIZE
+    vec = _i32((capacity,), one_chip)
+    scalar = _i32((), one_chip)
+    index = AdHocIndex(vec, vec, vec, scalar, scalar)
+    planes = (n_pages, PAGE_SIZE)
+    table = Table(
+        _i32(planes + (n_attrs,), one_chip),
+        _i32(planes, one_chip),
+        _i32(planes, one_chip),
+        scalar,
+    )
+    compiled = _build_pages_vap.lower(
+        index, table, (1,), scalar, bucket=64
+    ).compile()
+    text = compiled.as_text()
+    sorts = [line for line in text.splitlines() if re.search(r"\bsort\(", line)]
+    assert sorts
+    for line in sorts:
+        head = line.split("sort(")[0]
+        assert max(map(int, re.findall(r"\[(\d+)", head))) <= 64 * PAGE_SIZE
+    table_bytes = 4 * capacity * n_attrs
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < min(1e9, table_bytes / 2)
