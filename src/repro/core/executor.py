@@ -25,7 +25,7 @@ shard counts (tests/test_sharded_engine.py).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -62,15 +62,26 @@ from repro.core.planner import (
 )
 from repro.core.table import (
     ShardedTable,
+    Table,
+    copy_table,
     insert_rows,
     round_robin_layout,
     shard_table,
     sharded_insert_rows,
     sharded_update_rows,
+    table_bytes,
     unshard_table,
     update_rows,
 )
 from repro.obs import host_read, span
+
+
+class _Extent(NamedTuple):
+    """What a built fraction reads of a table (``BuiltIndex``):
+    the append watermark and the page size."""
+
+    n_rows: object
+    page_size: int
 
 
 @dataclass
@@ -131,6 +142,12 @@ class Database:
         num_shards: int = 1,
     ):
         self.tables: Dict[str, object] = dict(tables)
+        # Ownership (core/table.py's rule for the write programs): the
+        # table object this database's own last write returned, by
+        # name.  Only that table is written in place; any other one
+        # (given to the constructor, handed out by ``share_tables``,
+        # put into ``tables`` from outside) is copied once first.
+        self._owned: Dict[str, Table] = {}
         self.num_shards = 1
         self.indexes: Dict[str, BuiltIndex] = {}
         self.layouts: Dict[str, LayoutState] = {
@@ -209,8 +226,33 @@ class Database:
                 shard_table(t, num_shards) if num_shards > 1 else t
             )
         self.num_shards = num_shards
+        self._owned.clear()
         self._round_robin_cache.clear()
         self._zone_maps.clear()
+
+    def share_tables(self) -> Dict[str, object]:
+        """The tables, for another holder (a replica database): this
+        database gives up their ownership, so neither side writes them
+        in place and each copies a table before its first write."""
+        self._owned.clear()
+        return dict(self.tables)
+
+    def _writable(self, name: str) -> Table:
+        """Table ``name`` as this database may write it in place: the
+        table itself if the database owns it, else a copy made once by
+        ``copy_table`` inside a ``repro.table.own`` span."""
+        t = self.tables[name]
+        if self._owned.get(name) is not t:
+            with span("repro.table.own", table=name, bytes=table_bytes(t)):
+                t = copy_table(t)
+            self.tables[name] = self._owned[name] = t
+        return t
+
+    def _store(self, name: str, t) -> None:
+        """Install the table a write returned; a plain one is owned."""
+        self.tables[name] = t
+        if isinstance(t, Table):
+            self._owned[name] = t
 
     def table_is_round_robin(self, name: str) -> bool:
         """Cached: does ``name``'s shard layout follow the round-robin
@@ -814,9 +856,14 @@ class Database:
     def _exec_update(self, q: Query) -> ExecStats:
         t = self.tables[q.table]
         layout = self.layouts[q.table]
-        mutate = (
-            sharded_update_rows if isinstance(t, ShardedTable) else update_rows
-        )
+        if isinstance(t, ShardedTable):
+            mutate = sharded_update_rows
+        else:
+            mutate, t = update_rows, self._writable(q.table)
+        # The cost is charged from the table before the write.  A plain
+        # table's planes are donated to the write, so keep what the
+        # charge reads: ``n_rows`` (never donated) and the page size.
+        before = _Extent(t.n_rows, t.page_size)
         with span("repro.executor.dispatch", path="update", queries=1):
             los = jnp.asarray(q.los, jnp.int32)
             his = jnp.asarray(q.his, jnp.int32)
@@ -830,18 +877,18 @@ class Database:
                 self.clock_ms_i32(),
                 max_new=self.update_cap,
             )
-        self.tables[q.table] = new_t
+        self._store(q.table, new_t)
         n_upd = int(host_read(n_upd, "executor.update.rows"))
         # Row lookup: table scan unless an index matches the predicate.
         bi = self._choose_index(q)
         if bi is not None and bi.scheme in ("vap",):
-            frac = bi.built_fraction(t)
-            n_rows = int(host_read(t.n_rows, "executor.update.n_rows"))
+            frac = bi.built_fraction(before)
+            n_rows = int(host_read(before.n_rows, "executor.update.n_rows"))
             lookup = (1.0 - frac) * float(n_rows) + cm.INDEX_PROBE_COST * n_upd
             bi.last_used_ms = self.clock_ms
         else:
             width = scan_width_factor(layout, tuple(q.attrs), 0)
-            n_rows = int(host_read(t.n_rows, "executor.update.n_rows"))
+            n_rows = int(host_read(before.n_rows, "executor.update.n_rows"))
             lookup = float(n_rows) * (width / layout.n_attrs)
         maint = cm.tau_maintenance(n_upd) * max(
             len(self.indexes_on(q.table)), 0
@@ -858,9 +905,10 @@ class Database:
     def _exec_insert(self, q: Query) -> ExecStats:
         t = self.tables[q.table]
         rows = np.asarray(q.rows, np.int32)
-        mutate = (
-            sharded_insert_rows if isinstance(t, ShardedTable) else insert_rows
-        )
+        if isinstance(t, ShardedTable):
+            mutate = sharded_insert_rows
+        else:
+            mutate, t = insert_rows, self._writable(q.table)
         new_t = mutate(
             t,
             jnp.asarray(rows),
@@ -868,7 +916,7 @@ class Database:
             rows.shape[0],
             max_new=rows.shape[0],
         )
-        self.tables[q.table] = new_t
+        self._store(q.table, new_t)
         n = rows.shape[0]
         maint = cm.tau_maintenance(n) * max(len(self.indexes_on(q.table)), 0)
         cost = float(n) + maint

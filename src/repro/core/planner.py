@@ -26,6 +26,7 @@ it across replicas to send each query to the cheapest physical design.
 
 from __future__ import annotations
 
+import weakref
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -218,12 +219,18 @@ class QueryPlanner:
 
     Holds only references to live catalog state (tables + indexes), so
     plans always reflect the current configuration; all methods are
-    host-side Python.
+    host-side Python.  The database is held weakly: a database and its
+    planner form no reference cycle, so a dropped database frees its
+    tables at once, not at the next cyclic garbage collection.
     """
 
     def __init__(self, db):
-        self.db = db
+        self._db = weakref.ref(db)
         self._snap: Optional[dict] = None  # name -> IndexSnapshot
+
+    @property
+    def db(self):
+        return self._db()
 
     # -- catalog double buffering ----------------------------------------
     def begin_snapshot(self) -> None:

@@ -188,9 +188,12 @@ class ReplicaSet:
             )
         self.divergent = divergent
         self.dbs: List[Database] = [db]
+        # Every replica starts from the same table objects; none owns
+        # them, so each copies a table before its first write to it.
+        tables = db.share_tables()
         for _ in range(1, n_replicas):
             d = Database(
-                dict(db.tables),
+                tables,
                 time_per_unit_ms=db.time_per_unit_ms,
                 monitor_window=db.monitor.window,
                 monitor_max_age_ms=db.monitor.max_age_ms,

@@ -40,7 +40,8 @@ NEVER_TS = np.int32(2**31 - 1)  # begin_ts for unoccupied slots
 
 
 class Table(NamedTuple):
-    """Immutable paged column store (a pytree; all ops are functional)."""
+    """Paged column store (a pytree).  Reads are functional; the write
+    programs donate the planes (see the ownership rule at the mutators)."""
 
     data: jax.Array      # (n_pages, page_size, n_attrs) int32
     begin_ts: jax.Array  # (n_pages, page_size) int32
@@ -128,47 +129,78 @@ def conj_predicate_mask(table: Table, attrs, los, his) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Mutators (INSERT / UPDATE) -- functional, jit-friendly, fixed shapes
+# Mutators (INSERT / UPDATE) -- in place on the planes, fixed shapes
 # ---------------------------------------------------------------------------
+#
+# Ownership rule: ``insert_rows`` and ``update_rows`` donate the three
+# planes of the table they are given (``data``, ``begin_ts``,
+# ``end_ts``).  XLA writes the touched rows into those buffers, the
+# returned table holds them, and the given table's planes are deleted.
+# Its ``n_rows`` scalar is not donated: the returned table carries a new
+# one.  So a caller passes only a table it owns, one nothing else reads
+# afterwards.  A table shared with anyone else (a loaded table, one
+# another database holds) is first copied once by ``copy_table``, its
+# own program; ``Database`` keeps that rule (core/executor.py).
 
-@functools.partial(jax.jit, static_argnames=("max_new",))
-def insert_rows(table: Table, rows: jax.Array, ts, n_new, max_new: int) -> Table:
-    """Append ``n_new`` of the first ``max_new`` rows at timestamp ts.
 
-    ``rows`` is (max_new, n_attrs); only the first n_new are live.
-    Appends past capacity are dropped (callers size tables to avoid it).
-    """
-    del max_new  # shape is static via rows
+def _write_program(name: str, **jit_kwargs):
+    """``jax.jit`` a write program of ``(planes, n_rows, ...)`` with
+    ``planes`` donated, named ``name`` in the compiled module and the
+    profiler's trace (``jit_<name>``)."""
+
+    def wrap(fun):
+        fun.__name__ = fun.__qualname__ = name
+        return jax.jit(fun, donate_argnums=(0,), **jit_kwargs)
+
+    return wrap
+
+
+def _write_rows(data, base, rows, ok):
+    """``data`` with ``rows[i]`` at rid ``base + i`` where ``ok[i]``.
+    The rows are consecutive, so they lie in the few pages from
+    ``base``'s: those pages are read, the rows put in, and the pages
+    written back in place.  A scatter of the rows themselves would
+    relayout (row-wise) or flatten (per cell) the whole
+    attribute-major plane through a table-sized temporary."""
+    n_pages, psz, _ = data.shape
+    m = rows.shape[0]
+    k = min(-(-m // psz) + 1, n_pages)
+    start = jnp.minimum(base // psz, n_pages - k)
+    pages = jax.lax.dynamic_slice_in_dim(data, start, k, axis=0)
+    local = jnp.where(ok, base + jnp.arange(m, dtype=jnp.int32) - start * psz,
+                      k * psz)  # past the pages: dropped
+    pages = pages.reshape(k * psz, -1).at[local].set(rows, mode="drop")
+    return jax.lax.dynamic_update_slice_in_dim(
+        data, pages.reshape(k, psz, -1), start, axis=0)
+
+
+def _append(table: Table, rows: jax.Array, ts, n_new) -> Table:
     ts = jnp.asarray(ts, jnp.int32)
     base = table.n_rows
     idx = base + jnp.arange(rows.shape[0], dtype=jnp.int32)
     ok = (jnp.arange(rows.shape[0]) < n_new) & (idx < table.capacity)
-    idx = jnp.where(ok, idx, table.capacity - 1)  # parked writes are masked off
+    # Rows not written go past the last page, where the scatters drop
+    # them: on a real slot they would race the row written there.
+    idx = jnp.where(ok, idx, table.capacity)
     pg, sl = idx // table.page_size, idx % table.page_size
-    data = table.data.at[pg, sl].set(
-        jnp.where(ok[:, None], rows.astype(jnp.int32), table.data[pg, sl]))
-    begin = table.begin_ts.at[pg, sl].set(
-        jnp.where(ok, ts, table.begin_ts[pg, sl]))
-    end = table.end_ts.at[pg, sl].set(
-        jnp.where(ok, INF_TS, table.end_ts[pg, sl]))
+    data = _write_rows(table.data, base, rows.astype(jnp.int32), ok)
+    begin = table.begin_ts.at[pg, sl].set(ts, mode="drop")
+    end = table.end_ts.at[pg, sl].set(INF_TS, mode="drop")
     n_rows = jnp.minimum(base + jnp.asarray(n_new, jnp.int32),
                          jnp.asarray(table.capacity, jnp.int32))
     return Table(data, begin, end, n_rows)
 
 
-@functools.partial(jax.jit, static_argnames=("attrs", "max_new"))
-def update_rows(table: Table, attrs: tuple, los, his, set_attrs,
-                set_vals, ts, max_new: int) -> Tuple[Table, jax.Array]:
-    """MVCC UPDATE: terminate matching visible versions and append new ones.
+@_write_program("insert_rows", static_argnames=("max_new",))
+def _insert_program(planes, n_rows, rows, ts, n_new, max_new):
+    del max_new  # shape is static via rows
+    return _append(Table(*planes, n_rows), rows, ts, n_new)
 
-    Matches rows where the conjunctive range predicate over ``attrs``
-    holds, sets columns ``set_attrs`` (a *dynamic* int32 index array,
-    so randomised SET lists do not trigger recompilation) to
-    ``set_vals`` in the new versions.  At most ``max_new`` new versions
-    are materialised per call (the paper's update templates touch small
-    row counts; the cap keeps shapes static).  Returns
-    (new_table, n_updated).
-    """
+
+@_write_program("update_rows", static_argnames=("attrs", "max_new"))
+def _update_program(planes, n_rows, attrs, los, his, set_attrs, set_vals,
+                    ts, max_new):
+    table = Table(*planes, n_rows)
     ts = jnp.asarray(ts, jnp.int32)
     set_attrs = jnp.asarray(set_attrs, jnp.int32)
     set_vals = jnp.asarray(set_vals, jnp.int32)
@@ -185,13 +217,60 @@ def update_rows(table: Table, attrs: tuple, los, his, set_attrs,
     # Terminate old versions.
     end = table.end_ts.at[pg, sl].set(
         jnp.where(sel_ok, ts, table.end_ts[pg, sl]))
-    old_rows = table.data[pg, sl]  # (max_new, n_attrs)
+    # (max_new, n_attrs), gathered cell by cell: a row-wise gather makes
+    # XLA move the whole attribute-major ``data`` plane into a row-major
+    # layout first.
+    attrs_all = jnp.arange(table.n_attrs, dtype=jnp.int32)
+    old_rows = table.data[pg[:, None], sl[:, None], attrs_all[None, :]]
     new_rows = old_rows.at[:, set_attrs].set(
         jnp.broadcast_to(set_vals, (old_rows.shape[0], set_vals.shape[0])))
     table = Table(table.data, table.begin_ts, end, table.n_rows)
     n_upd = jnp.minimum(n_match, max_new)
-    table = insert_rows(table, new_rows, ts, n_upd, max_new=max_new)
-    return table, n_upd
+    return _append(table, new_rows, ts, n_upd), n_upd
+
+
+def insert_rows(table: Table, rows: jax.Array, ts, n_new, max_new: int) -> Table:
+    """Append ``n_new`` of the first ``max_new`` rows at timestamp ts.
+
+    ``rows`` is (max_new, n_attrs); only the first n_new are live.
+    Appends past capacity are dropped (callers size tables to avoid it).
+    Writes in place: ``table``'s planes are donated to the returned
+    table, so the caller must own ``table`` (the ownership rule above).
+    """
+    return _insert_program(table[:3], table.n_rows, rows, ts, n_new,
+                           max_new=max_new)
+
+
+def update_rows(table: Table, attrs: tuple, los, his, set_attrs,
+                set_vals, ts, max_new: int) -> Tuple[Table, jax.Array]:
+    """MVCC UPDATE: terminate matching visible versions and append new ones.
+
+    Matches rows where the conjunctive range predicate over ``attrs``
+    holds, sets columns ``set_attrs`` (a *dynamic* int32 index array,
+    so randomised SET lists do not trigger recompilation) to
+    ``set_vals`` in the new versions.  At most ``max_new`` new versions
+    are materialised per call (the paper's update templates touch small
+    row counts; the cap keeps shapes static).  Returns
+    (new_table, n_updated).  Writes in place: ``table``'s planes are
+    donated to ``new_table``, so the caller must own ``table`` (the
+    ownership rule above).
+    """
+    return _update_program(table[:3], table.n_rows, attrs, los, his,
+                           set_attrs, set_vals, ts, max_new=max_new)
+
+
+@jax.jit
+def copy_table(table: Table) -> Table:
+    """``table`` in new plane buffers, for a writer that does not own it:
+    one read and one write of each plane.  ``n_rows`` is shared, as no
+    write donates it."""
+    return Table(jnp.copy(table.data), jnp.copy(table.begin_ts),
+                 jnp.copy(table.end_ts), table.n_rows)
+
+
+def table_bytes(table: Table) -> int:
+    """Bytes of the planes a copy of ``table`` writes."""
+    return sum(int(x.size) * x.dtype.itemsize for x in table[:3])
 
 
 # ---------------------------------------------------------------------------
@@ -474,8 +553,8 @@ def sharded_insert_rows(st: ShardedTable, rows: jax.Array, ts, n_new,
                         max_new: int) -> ShardedTable:
     """Sharded INSERT: same append-at-watermark semantics as
     ``insert_rows``; each row is scattered to the shard owning its
-    global page.  Parked (masked-off) writes target the owning shard's
-    last slot with its current value, mirroring the single-table op."""
+    global page.  Rows not written go past the shard's last page, where
+    the scatters drop them, as in the single-table op."""
     del max_new
     S = len(st.shards)
     psz = st.page_size
@@ -492,15 +571,10 @@ def sharded_insert_rows(st: ShardedTable, rows: jax.Array, ts, n_new,
     new_shards = []
     for s, t in enumerate(st.shards):
         ok_s = ok & (owner == s)
-        lp_s = jnp.where(ok_s, lp, t.n_pages - 1)
-        sl_s = jnp.where(ok_s, sl, psz - 1)
-        data = t.data.at[lp_s, sl_s].set(
-            jnp.where(ok_s[:, None], rows.astype(jnp.int32),
-                      t.data[lp_s, sl_s]))
-        begin = t.begin_ts.at[lp_s, sl_s].set(
-            jnp.where(ok_s, ts, t.begin_ts[lp_s, sl_s]))
-        end = t.end_ts.at[lp_s, sl_s].set(
-            jnp.where(ok_s, INF_TS, t.end_ts[lp_s, sl_s]))
+        lp_s = jnp.where(ok_s, lp, t.n_pages)  # dropped past the last page
+        data = t.data.at[lp_s, sl].set(rows.astype(jnp.int32), mode="drop")
+        begin = t.begin_ts.at[lp_s, sl].set(ts, mode="drop")
+        end = t.end_ts.at[lp_s, sl].set(INF_TS, mode="drop")
         new_shards.append(Table(data, begin, end,
                                 local_n_rows(n_rows, s, S, psz, t.n_pages)))
     return ShardedTable(tuple(new_shards), n_rows)

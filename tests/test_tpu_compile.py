@@ -225,3 +225,35 @@ def test_vap_build_merges_without_capacity_sort(one_chip, n_pages, n_attrs):
     table_bytes = 4 * capacity * n_attrs
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < min(1e9, table_bytes / 2)
+
+
+@pytest.mark.parametrize(
+    "n_pages, n_attrs",
+    [(N_PAGES, N_ATTRS), (35157, 201)],
+    ids=["narrow_10M", "wide_6M"],
+)
+def test_update_writes_its_planes_in_place(one_chip, n_pages, n_attrs):
+    """An UPDATE at the benchmark's table shapes writes into the planes
+    it is given: all three are aliased to the outputs, no second
+    table-sized output is allocated, and its temporaries stay small
+    (the capacity argsort)."""
+    from repro.core.table import _update_program
+
+    planes = (n_pages, PAGE_SIZE)
+    table = (
+        _i32(planes + (n_attrs,), one_chip),
+        _i32(planes, one_chip),
+        _i32(planes, one_chip),
+    )
+    scalar = _i32((), one_chip)
+    bounds = _i32((1,), one_chip)
+    sets = _i32((3,), one_chip)
+    compiled = _update_program.lower(
+        table, scalar, (1,), bounds, bounds, sets, sets, scalar, max_new=512
+    ).compile()
+    mem = compiled.memory_analysis()
+    # The chip's tiled layout pads the page axis to a multiple of 8.
+    plane_bytes = 4 * n_pages * PAGE_SIZE * (n_attrs + 2)
+    assert plane_bytes <= mem.alias_size_in_bytes < 1.001 * plane_bytes
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 4096
+    assert mem.temp_size_in_bytes < 0.5e9
