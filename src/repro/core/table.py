@@ -197,6 +197,30 @@ def _insert_program(planes, n_rows, rows, ts, n_new, max_new):
     return _append(Table(*planes, n_rows), rows, ts, n_new)
 
 
+def first_matches(match: jax.Array, max_new: int):
+    """The first ``max_new`` True entries of the (n_pages, page_size)
+    mask ``match``, in rid order, found from per-page counts: entry k
+    lies in the first page whose running count of matches exceeds k, at
+    the slot of its rank among that page's matches.  Its cost follows
+    the pages and the ``max_new`` rows gathered, not the capacity.
+
+    Returns (rids, n_match): ``rids[k]`` is ``np.flatnonzero(match)[k]``
+    for every k < ``min(n_match, max_new)``; every later entry is the
+    capacity, one past the last slot, so a scatter with ``mode="drop"``
+    skips it and no real slot is written twice."""
+    n_pages, psz = match.shape
+    ends = jnp.cumsum(jnp.sum(match, axis=1, dtype=jnp.int32))
+    k = jnp.arange(max_new, dtype=jnp.int32)[:, None]
+    before = ends[None, :] <= k  # (max_new, n_pages): pages wholly before k
+    page = jnp.sum(before, axis=1, dtype=jnp.int32)  # n_pages past the matches
+    rank = k[:, 0] - jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    row = jnp.cumsum(match[jnp.minimum(page, n_pages - 1)], axis=1,
+                     dtype=jnp.int32)
+    slot = jnp.sum(row <= rank[:, None], axis=1, dtype=jnp.int32)
+    rids = jnp.where(page < n_pages, page * psz + slot, n_pages * psz)
+    return rids, ends[-1]
+
+
 @_write_program("update_rows", static_argnames=("attrs", "max_new"))
 def _update_program(planes, n_rows, attrs, los, his, set_attrs, set_vals,
                     ts, max_new):
@@ -205,23 +229,18 @@ def _update_program(planes, n_rows, attrs, los, his, set_attrs, set_vals,
     set_attrs = jnp.asarray(set_attrs, jnp.int32)
     set_vals = jnp.asarray(set_vals, jnp.int32)
     match = conj_predicate_mask(table, attrs, los, his) & visible_mask(table, ts)
-    flat_match = match.reshape(-1)
-    n_match = jnp.sum(flat_match, dtype=jnp.int32)
-
-    # Select up to max_new matching rids (in rid order).
-    order = jnp.argsort(~flat_match, stable=True)  # matches first
-    rids = order[:max_new].astype(jnp.int32)
-    sel_ok = jnp.arange(max_new) < jnp.minimum(n_match, max_new)
+    rids, n_match = first_matches(match, max_new)
     pg, sl = rids // table.page_size, rids % table.page_size
 
-    # Terminate old versions.
-    end = table.end_ts.at[pg, sl].set(
-        jnp.where(sel_ok, ts, table.end_ts[pg, sl]))
+    # Terminate old versions; entries past the matches fall past the
+    # last page and are dropped.
+    end = table.end_ts.at[pg, sl].set(ts, mode="drop")
     # (max_new, n_attrs), gathered cell by cell: a row-wise gather makes
     # XLA move the whole attribute-major ``data`` plane into a row-major
     # layout first.
     attrs_all = jnp.arange(table.n_attrs, dtype=jnp.int32)
-    old_rows = table.data[pg[:, None], sl[:, None], attrs_all[None, :]]
+    pg_in = jnp.minimum(pg, table.n_pages - 1)
+    old_rows = table.data[pg_in[:, None], sl[:, None], attrs_all[None, :]]
     new_rows = old_rows.at[:, set_attrs].set(
         jnp.broadcast_to(set_vals, (old_rows.shape[0], set_vals.shape[0])))
     table = Table(table.data, table.begin_ts, end, table.n_rows)
@@ -250,7 +269,11 @@ def update_rows(table: Table, attrs: tuple, los, his, set_attrs,
     so randomised SET lists do not trigger recompilation) to
     ``set_vals`` in the new versions.  At most ``max_new`` new versions
     are materialised per call (the paper's update templates touch small
-    row counts; the cap keeps shapes static).  Returns
+    row counts; the cap keeps shapes static): the first ``max_new``
+    matches in rid order, found from per-page match counts
+    (``first_matches``), with no sort of the capacity.  Selection
+    entries past the matches point one past the last slot, where the
+    ``end_ts`` scatter drops them.  Returns
     (new_table, n_updated).  Writes in place: ``table``'s planes are
     donated to ``new_table``, so the caller must own ``table`` (the
     ownership rule above).
@@ -588,10 +611,13 @@ def sharded_update_rows(st: ShardedTable, attrs: tuple, los, his, set_attrs,
 
     The "first max_new matches in global rid order" selection cannot be
     made per shard (matches interleave across shards in rid order), so
-    per-shard match masks are scattered into one global flat vector and
-    the selection runs on it exactly like the single-table op; the
-    chosen rids are then routed back to their owning shards for
-    version termination and the gather of pre-image rows.
+    per-shard match masks are scattered into one global
+    (pages, page_size) mask and ``first_matches`` selects on it exactly
+    as the single-table op does; the chosen rids are then routed back
+    to their owning shards for version termination and the gather of
+    pre-image rows.  Entries past the matches, and entries another
+    shard owns, fall past a shard's last page, where its ``end_ts``
+    scatter drops them.
     """
     S = len(st.shards)
     psz = st.page_size
@@ -605,11 +631,8 @@ def sharded_update_rows(st: ShardedTable, attrs: tuple, los, his, set_attrs,
         m = conj_predicate_mask(t, attrs, los, his) & visible_mask(t, ts)
         rid_map = global_rids(t.n_pages, s, S, psz)
         flat_match = flat_match.at[rid_map].set(m.reshape(-1))
-    n_match = jnp.sum(flat_match, dtype=jnp.int32)
-
-    order = jnp.argsort(~flat_match, stable=True)  # matches first
-    rids = order[:max_new].astype(jnp.int32)
-    sel_ok = jnp.arange(max_new) < jnp.minimum(n_match, max_new)
+    rids, n_match = first_matches(flat_match.reshape(-1, psz), max_new)
+    sel_ok = rids < capacity
     gp, sl = rids // psz, rids % psz
     owner, lp = gp % S, gp // S
 
@@ -617,11 +640,8 @@ def sharded_update_rows(st: ShardedTable, attrs: tuple, los, his, set_attrs,
     new_shards = []
     for s, t in enumerate(st.shards):
         own_s = owner == s
-        ok_s = sel_ok & own_s
-        lp_s = jnp.where(ok_s, lp, t.n_pages - 1)
-        sl_s = jnp.where(ok_s, sl, psz - 1)
-        end = t.end_ts.at[lp_s, sl_s].set(
-            jnp.where(ok_s, ts, t.end_ts[lp_s, sl_s]))
+        lp_s = jnp.where(sel_ok & own_s, lp, t.n_pages)  # dropped
+        end = t.end_ts.at[lp_s, sl].set(ts, mode="drop")
         vals = t.data[jnp.where(own_s, lp, 0).clip(0, t.n_pages - 1),
                       jnp.where(own_s, sl, 0)]
         old_rows = jnp.where(own_s[:, None], vals, old_rows)
