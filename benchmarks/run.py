@@ -13,10 +13,12 @@ import traceback
 
 
 def bench_kernels():
-    """Pallas filter_agg vs pure-jnp reference.  On the CPU the kernel
-    runs in interpret mode (the comparison point is correctness + call
-    overhead); on a TPU it is the compiled kernel."""
+    """One query through the Pallas batched kernel (a batch of one) vs
+    the pure-jnp reference.  On the CPU the kernel runs in interpret
+    mode (the comparison point is correctness + call overhead); on a
+    TPU it is the compiled kernel."""
     import jax
+    import numpy as np
 
     from benchmarks.common import emit
     from repro.bench_db.schema import make_tuner_db
@@ -37,15 +39,18 @@ def bench_kernels():
     us_ref = timed(lambda: filter_agg_ref(
         t.data[:, :, 1], t.data[:, :, 1], t.data[:, :, 2], t.begin_ts,
         t.end_ts, lo, hi, ops.I32_MIN, ops.I32_MAX, 0)[0].block_until_ready())
-    us_pal = timed(lambda: ops.scan_table(
-        t, (1,), (lo,), (hi,), ts=0, agg_attr=2)[0].block_until_ready())
+    us_pal = timed(lambda: ops.scan_table_batched(
+        t, (1,), np.array([[lo]]), np.array([[hi]]), np.zeros(1, np.int32),
+        agg_attr=2)[0].block_until_ready())
     emit("kernel.filter_agg_ref_jnp", us_ref, "pure-jnp oracle")
     if ops.interpret_mode():
         emit("kernel.filter_agg_pallas_interpret", us_pal,
-             "pl.pallas_call interpret=True (CPU correctness mode)")
+             "ops.scan_table_batched at B=1, interpret=True "
+             "(CPU correctness mode)")
     else:
         emit("kernel.filter_agg_pallas_compiled", us_pal,
-             f"pl.pallas_call on {jax.devices()[0].device_kind}")
+             f"ops.scan_table_batched at B=1 on "
+             f"{jax.devices()[0].device_kind}")
 
 
 def bench_roofline():
@@ -94,8 +99,7 @@ def main() -> None:
                             fault_recovery, fig2_schemes,
                             fig6_decision_logic, fig7_holistic,
                             fig8_affinity, fig9_layout,
-                            fig10_adaptability, fused_shard_scan,
-                            mesh_scan, replica_routing, serving_slo,
+                            fig10_adaptability, mesh_scan, replica_routing, serving_slo,
                             shard_tuning, sharded_scan)
     from benchmarks import common
 
@@ -127,8 +131,6 @@ def main() -> None:
         ("crack_on_scan", lambda: crack_on_scan.run(
             total=160 if quick else 240,
             phase_len=55 if quick else 80, quiet=True)),
-        ("fused_shard", lambda: fused_shard_scan.run(
-            bursts=2 if quick else 3, quiet=True)),
         # burst size NOT reduced under --quick: the headline is burst
         # amortization of the mesh dispatch's fixed cost, which needs
         # the full burst to be meaningful (see mesh_scan docstring)

@@ -2,17 +2,15 @@
 
 ``Database(num_shards=S)`` partitions pages round-robin across S
 shards; ``execute_batch`` then runs each plan group as ONE stacked
-single dispatch for any shard count (PR 5's fused layout; see
-benchmarks/fused_shard_scan.py for fused-vs-loop), or one device per
-shard via ``jax.pmap`` when the host exposes enough devices.  Results
-are bit-identical across shard counts (asserted here against the
-1-shard engine), so this bench isolates the *dispatch* cost of
-sharding: on one CPU core it should be roughly flat vs. 1 shard; on
-multi-device deployments each shard scans 1/S of the pages in
-parallel.
+single dispatch for any shard count, or on a ``shard_map`` mesh when
+the host's devices can place the shards.  Results are bit-identical
+across shard counts (asserted here against the 1-shard engine), so
+this bench isolates the *dispatch* cost of sharding: on one CPU core
+it should be roughly flat vs. 1 shard; on multi-device deployments
+each shard scans 1/S of the pages in parallel.
 
     PYTHONPATH=src python -m benchmarks.sharded_scan
-    # pmap fan-out on a CPU host:
+    # mesh fan-out on a CPU host:
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python -m benchmarks.sharded_scan
 """
@@ -22,7 +20,6 @@ import time
 
 from benchmarks.common import emit
 from repro.api import Database, IndexDescriptor, QueryGen, make_tuner_db
-from repro.parallel.sharding import shard_fanout_devices
 
 
 def _mk_db(src, num_shards: int, with_index: bool):
@@ -67,20 +64,14 @@ def run(n_queries: int = 64, n_rows: int = 20_000, page_size: int = 256,
             assert stats == base_stats, \
                 f"{label}: {S}-shard results diverge from 1-shard"
 
-            fanout = "pmap" if shard_fanout_devices(S) is not None \
-                else "fused single dispatch"
+            tier = db.engine.last_tier
             rel = base_us / us_q
             results[(label, S)] = us_q
             emit(f"sharded_scan.{label}.shards{S}", us_q,
-                 f"{n_queries}-query burst, {fanout} fan-out, "
+                 f"{n_queries}-query burst, tier {tier}, "
                  f"{rel:.2f}x vs 1 shard")
             if not quiet:
-                print(f"# {label} S={S}: {us_q:.1f} us/q ({fanout})")
-    devs = shard_fanout_devices(max(shard_counts))
-    emit("sharded_scan.fanout_devices",
-         float(len(devs) if devs else 1),
-         "devices available for one-device-per-shard pmap fan-out",
-         direction="info")
+                print(f"# {label} S={S}: {us_q:.1f} us/q ({tier})")
     return results
 
 

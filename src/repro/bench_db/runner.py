@@ -57,8 +57,7 @@ class ExecOptions:
     # scans ride a shard_map device mesh whenever the local devices
     # can place the shard axis; False = force the single-device
     # stacked dispatch; True = REQUIRE a mesh -- a placement failure
-    # raises instead of silently falling back (the telemetry fix for
-    # the old pmap path's silent downgrade).  mesh_query_axis > 1
+    # raises instead of silently falling back.  mesh_query_axis > 1
     # additionally folds the 2-D query-batch axis over read bursts
     # (claims n_shards x mesh_query_axis devices).
     mesh: Optional[bool] = None
@@ -324,9 +323,9 @@ class RunResult:
     build_throttle_deferrals: int = 0   # drains deferred under pressure
     build_shed_quanta: int = 0          # quanta dropped by load shedding
     # Dispatch-strategy telemetry: execution tier -> queries served by
-    # it (ScanEngine.last_tier: single / loop / vmap-stacked / kernel
-    # / pmap / shard_map).  Benchmarks assert the tier they mean to
-    # measure instead of trusting a silent fallback.
+    # it (ScanEngine.TIERS: single / vmap-stacked / kernel /
+    # shard_map).  Benchmarks assert the tier they mean to measure
+    # instead of trusting a silent fallback.
     execution_tiers: Dict[str, int] = field(default_factory=dict)
     # Replica-tier telemetry (ReplicaOptions.n_replicas > 1): the
     # replica id every scan / read burst was routed to, in dispatch

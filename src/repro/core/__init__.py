@@ -4,7 +4,8 @@ The paper's contribution as composable pieces:
 
 * ``table`` / ``index`` / ``hybrid_scan`` -- the storage engine: paged
   MVCC column store, partially-built ad-hoc indexes (FULL/VBP/VAP
-  population schemes) and the value-agnostic hybrid scan operator.
+  population schemes) and the value-agnostic hybrid scan operator, run
+  by ``engine`` as batches (a single statement is a batch of one).
 * ``classifier`` / ``forecaster`` / ``knapsack`` / ``cost_model`` --
   the ML decision-logic components of Algorithm 1.
 * ``monitor`` / ``executor`` -- workload monitoring and the query
@@ -18,15 +19,13 @@ The paper's contribution as composable pieces:
 from repro.core.build_service import (BuildQuantum, BuildService, CyclePlan,
                                       apply_quantum)
 from repro.core.cost_model import IndexDescriptor
-from repro.core.engine import ScanEngine, ShardScanResult
+from repro.core.engine import ScanEngine
 from repro.core.executor import Database, ExecStats, Query
 from repro.core.hybrid_scan import (BatchScanResult, HybridPrefixResult,
-                                    ScanResult, batched_full_table_scan,
+                                    batched_full_table_scan,
                                     batched_hybrid_index_prefix,
                                     batched_hybrid_scan,
-                                    batched_pure_index_scan,
-                                    full_table_scan, hybrid_scan,
-                                    pure_index_scan)
+                                    batched_pure_index_scan)
 from repro.core.index import (AdHocIndex, ShardedIndex, ShardedVbpState,
                               VbpState, advance_build_shard, build_full,
                               build_pages_vap, make_index,
@@ -47,12 +46,12 @@ __all__ = [
     "BuiltIndex", "CyclePlan", "Database", "ExecStats", "apply_quantum",
     "HybridPrefixResult", "IndexDescriptor", "PredictiveTuner", "Query",
     "QueryPlanner", "ReplicaSet", "ReplicaSetTuner", "ScanEngine", "ScanPlan",
-    "ScanResult", "ShardScanResult", "cluster_assignments",
+    "cluster_assignments",
     "ShardedIndex", "ShardedTable", "ShardedVbpState", "Table", "TunerConfig",
     "VbpState", "batched_full_table_scan", "batched_hybrid_index_prefix",
     "batched_hybrid_scan", "batched_pure_index_scan", "build_full",
-    "build_pages_vap", "full_table_scan", "hybrid_scan", "load_table",
+    "build_pages_vap", "load_table",
     "make_dl_tuner", "make_index", "make_sharded_index", "make_sharded_vbp",
-    "make_table", "make_vbp", "pure_index_scan", "scan_cost", "shard_table",
+    "make_table", "make_vbp", "scan_cost", "shard_table",
     "sharded_build_pages_vap", "unshard_table",
 ]

@@ -5,7 +5,7 @@ configuration, eta(r, I) under a hypothetical extra index I, and the
 maintenance cost tau(w, I) an index imposes on a mutator.  Costs are
 in *tuple-touch units*: 1 unit == inspecting one tuple.  The same
 units are produced by the execution engine's measured statistics
-(ScanResult.pages_scanned etc.), so estimated and observed utilities
+(BatchScanResult.pages_scanned etc.), so estimated and observed utilities
 are directly comparable -- that is what lets the forecaster's
 reinforcement signal be bootstrapped from what-if estimates (Algorithm
 1) and then refined with observations.

@@ -5,9 +5,9 @@ The execution half of the planner/engine split (the planner lives in
 models -- it receives an access path, raw index state and per-query
 bounds, and owns the dispatch strategy:
 
-* plain ``Table``   -- the single-table operators of ``hybrid_scan``
-  (vmapped jnp forms on CPU, the multi-query Pallas kernel via
-  ``kernels.ops`` on TPU; the hybrid path stitches the kernel's
+* plain ``Table``   -- the batched operators of ``hybrid_scan``
+  (vmapped jnp forms, or with ``use_kernel`` the multi-query Pallas
+  kernel via ``kernels.ops``; the hybrid path stitches the kernel's
   per-query ``start_pages`` table suffix to the jnp index prefix).
 * ``ShardedTable``  -- ONE dispatch regardless of shard count: the
   shards are stacked on a leading axis (``table.stacked_shards``, a
@@ -16,23 +16,25 @@ bounds, and owns the dispatch strategy:
   grows.  With ``use_kernel`` the fused Pallas kernel runs the same
   layout as a (shard, page-block, query) grid with a per-(shard,
   query) scalar-prefetched ``start_pages`` table
-  (``kernels.batched_filter_agg.sharded_batched_filter_agg``).  The
-  legacy per-shard loop fan-out survives as the ``*_loop`` forms --
-  the parity oracle (tests/test_fused_shard_scan.py) and the
-  benchmark baseline (benchmarks/fused_shard_scan.py).  When the
-  local devices can place the shard axis (``parallel.mesh``), the
+  (``kernels.batched_filter_agg.sharded_batched_filter_agg``).  When
+  the local devices can place the shard axis (``parallel.mesh``), the
   same stacked pytree rides a named mesh via ``shard_map`` -- the
   mesh forms below -- and every dispatch records its execution tier
-  (``ScanEngine.last_tier``: loop / vmap-stacked / kernel / pmap /
+  (``ScanEngine.last_tier``: single / vmap-stacked / kernel /
   shard_map) for the executor's telemetry.
+
+Every scan, a single statement included, is a batch: one statement
+runs as a batch of one through ``ScanEngine.scan_batch``, so each plan
+family has one operator per tier.
 
 Bit-identity contract (tests/test_sharded_engine.py): for any shard
 count, every aggregate and accounting field equals the single-shard
 value.  int32 sums wrap associatively/commutatively, so per-shard
 partials reduce to the exact single-shard bit pattern in any order --
-which is also why the stacked forms' axis reductions and the loop
-forms' pairwise tree reductions agree bit for bit, and why the padded
-shard grid is safe: padding pages carry ``begin_ts == NEVER_TS``, are
+which is why the stacked forms' axis reductions agree bit for bit
+with a per-shard loop over the shards tuple (the reference in
+tests/test_fused_shard_scan.py), and why the padded shard grid is
+safe: padding pages carry ``begin_ts == NEVER_TS``, are
 invisible to every snapshot, and contribute exact int32 zeros.
 
 The hybrid scan's cross-shard stitch works in two passes inside one
@@ -66,7 +68,6 @@ results AND accounting for 1 and 4 shards.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,10 +81,6 @@ from repro.core.hybrid_scan import (
     batched_hybrid_scan_masked,
     batched_masked_index_side,
     batched_pure_index_scan,
-    full_table_scan,
-    hybrid_scan,
-    hybrid_scan_masked,
-    pure_index_scan,
 )
 from repro.core.index import (
     AdHocIndex,
@@ -106,37 +103,9 @@ from repro.parallel.mesh import (
     stacked_specs,
 )
 
-# vmap/pmap axis prefixes: map the leading shard axis of every leaf.
+# vmap axis prefixes: map the leading shard axis of every leaf.
 _TABLE_AXES = Table(0, 0, 0, 0)
 _INDEX_AXES = AdHocIndex(0, 0, 0, 0, 0)
-
-
-class ShardScanResult(NamedTuple):
-    """Single-query aggregates + accounting over sharded storage.
-
-    Scalar fields are bit-identical to the single-shard ``ScanResult``;
-    ``contribs`` replaces the global contrib plane with one
-    (local_pages, page_size) int32 plane per shard (the executor's
-    join path consumes them per shard).
-    """
-
-    agg_sum: jax.Array
-    count: jax.Array
-    contribs: Tuple[jax.Array, ...]
-    pages_scanned: jax.Array
-    entries_probed: jax.Array
-    start_page: jax.Array
-
-
-def tree_reduce(vals, op=jnp.add):
-    """Pairwise (tree-shaped) reduction of per-shard partials."""
-    vals = list(vals)
-    while len(vals) > 1:
-        nxt = [op(vals[i], vals[i + 1]) for i in range(0, len(vals) - 1, 2)]
-        if len(vals) % 2:
-            nxt.append(vals[-1])
-        vals = nxt
-    return vals[0]
 
 
 def _used_pages(st: ShardedTable) -> jax.Array:
@@ -148,8 +117,8 @@ def _used_pages(st: ShardedTable) -> jax.Array:
 def _shard_index_probe(t, ix, s, S, key_attrs, attrs, lo, hi, ts):
     """Probe one shard's local index: masks, local page/slot of each
     entry, and this shard's contribution to the per-query rho_m (in
-    *global* page ids).  ``s`` may be a Python int (loop fan-out) or a
-    traced scalar (stacked fan-out); the arithmetic is identical."""
+    *global* page ids).  ``s`` may be a Python int or a traced scalar
+    (stacked and mesh fan-out); the arithmetic is identical."""
     psz = t.page_size
     lo_key, hi_key = _predicate_key_bounds(key_attrs, attrs, lo, hi)
     entry_mask, rids = index_range_scan(ix, lo_key, hi_key)
@@ -168,113 +137,6 @@ def _shard_table_mask(t, s, S, attrs, lo, hi, ts, start_page):
     g_page_ids = (jnp.arange(t.n_pages, dtype=jnp.int32) * S + s)[:, None]
     mask = conj_predicate_mask(t, attrs, lo, hi) & visible_mask(t, ts)
     return mask & (g_page_ids >= start_page)
-
-
-# ---------------------------------------------------------------------------
-# Sharded single-query scans (contrib planes for the join path)
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("attrs", "agg_attr"))
-def sharded_full_table_scan(
-    st: ShardedTable, attrs: tuple, los, his, ts, agg_attr: int
-) -> ShardScanResult:
-    sums, cnts, contribs = [], [], []
-    for t in st.shards:
-        mask = conj_predicate_mask(t, attrs, los, his) & visible_mask(t, ts)
-        vals = t.data[:, :, agg_attr]
-        sums.append(jnp.sum(jnp.where(mask, vals, 0), dtype=jnp.int32))
-        cnts.append(jnp.sum(mask, dtype=jnp.int32))
-        contribs.append(mask.astype(jnp.int32))
-    z = jnp.zeros((), jnp.int32)
-    return ShardScanResult(
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        tuple(contribs),
-        _used_pages(st),
-        z,
-        z,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def sharded_hybrid_scan(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-) -> ShardScanResult:
-    S = len(st.shards)
-    probes = [
-        _shard_index_probe(t, ix, s, S, key_attrs, attrs, los, his, ts)
-        for s, (t, ix) in enumerate(zip(st.shards, index.shards))
-    ]
-    rho_m = tree_reduce([p[5] for p in probes], jnp.maximum)
-    start_page = jnp.maximum(rho_m, index.built_pages)  # rho_i + 1
-
-    sums, cnts, ents, contribs = [], [], [], []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-        idx_match, gpg, pg, sl, entry_mask, _ = probes[s]
-        idx_keep = idx_match & (gpg < start_page)
-        tbl_mask = _shard_table_mask(t, s, S, attrs, los, his, ts, start_page)
-        vals = t.data[:, :, agg_attr]
-        keep_vals = jnp.where(idx_keep, vals[pg, sl], 0)
-        idx_sum = jnp.sum(keep_vals, dtype=jnp.int32)
-        tbl_sum = jnp.sum(jnp.where(tbl_mask, vals, 0), dtype=jnp.int32)
-        sums.append(idx_sum + tbl_sum)
-        idx_cnt = jnp.sum(idx_keep, dtype=jnp.int32)
-        cnts.append(idx_cnt + jnp.sum(tbl_mask, dtype=jnp.int32))
-        ents.append(jnp.sum(entry_mask, dtype=jnp.int32))
-        contrib = jnp.zeros((t.n_pages, t.page_size), jnp.int32)
-        contrib = contrib.at[pg, sl].add(idx_keep.astype(jnp.int32))
-        contribs.append(contrib + tbl_mask.astype(jnp.int32))
-    pages = jnp.clip(_used_pages(st) - start_page, 0, None).astype(jnp.int32)
-    return ShardScanResult(
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        tuple(contribs),
-        pages,
-        tree_reduce(ents),
-        start_page.astype(jnp.int32),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def sharded_pure_index_scan(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-) -> ShardScanResult:
-    S = len(st.shards)
-    sums, cnts, ents, contribs = [], [], [], []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-        idx_match, _gpg, pg, sl, entry_mask, _ = _shard_index_probe(
-            t, ix, s, S, key_attrs, attrs, los, his, ts
-        )
-        vals = t.data[:, :, agg_attr]
-        match_vals = jnp.where(idx_match, vals[pg, sl], 0)
-        sums.append(jnp.sum(match_vals, dtype=jnp.int32))
-        cnts.append(jnp.sum(idx_match, dtype=jnp.int32))
-        ents.append(jnp.sum(entry_mask, dtype=jnp.int32))
-        contrib = jnp.zeros((t.n_pages, t.page_size), jnp.int32)
-        contribs.append(contrib.at[pg, sl].add(idx_match.astype(jnp.int32)))
-    return ShardScanResult(
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        tuple(contribs),
-        jnp.zeros((), jnp.int32),
-        tree_reduce(ents),
-        jnp.asarray(st.n_pages, jnp.int32),
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -314,130 +176,6 @@ def _pershard_stitch(t, ix, s, S, key_attrs, attrs, lo, hi, ts):
     return idx_keep, pg, sl, entry_mask, tbl_mask, pages, gstart
 
 
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def sharded_hybrid_scan_pershard(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-) -> ShardScanResult:
-    S = len(st.shards)
-    sums, cnts, ents, contribs, pages, gstarts = [], [], [], [], [], []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-        idx_keep, pg, sl, entry_mask, tbl_mask, pages_s, gstart = (
-            _pershard_stitch(t, ix, s, S, key_attrs, attrs, los, his, ts)
-        )
-        vals = t.data[:, :, agg_attr]
-        keep_vals = jnp.where(idx_keep, vals[pg, sl], 0)
-        idx_sum = jnp.sum(keep_vals, dtype=jnp.int32)
-        tbl_sum = jnp.sum(jnp.where(tbl_mask, vals, 0), dtype=jnp.int32)
-        sums.append(idx_sum + tbl_sum)
-        idx_cnt = jnp.sum(idx_keep, dtype=jnp.int32)
-        cnts.append(idx_cnt + jnp.sum(tbl_mask, dtype=jnp.int32))
-        ents.append(jnp.sum(entry_mask, dtype=jnp.int32))
-        contrib = jnp.zeros((t.n_pages, t.page_size), jnp.int32)
-        contrib = contrib.at[pg, sl].add(idx_keep.astype(jnp.int32))
-        contribs.append(contrib + tbl_mask.astype(jnp.int32))
-        pages.append(pages_s)
-        gstarts.append(gstart)
-    return ShardScanResult(
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        tuple(contribs),
-        tree_reduce(pages),
-        tree_reduce(ents),
-        tree_reduce(gstarts, jnp.minimum),
-    )
-
-
-# ---------------------------------------------------------------------------
-# Masked (bitmap) stitch: coverage partitions pages, no stitch point
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def _sharded_masked_scan(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-    covered,
-):
-    """Per-shard masked stitch bodies; ``covered`` is the (S, max_pages)
-    bool bitmap over LOCAL page ids (``PageCoverage.stacked_mask``)."""
-    S = len(st.shards)
-    sums, cnts, ents, contribs = [], [], [], []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-        lc = covered[s, : t.n_pages]
-        idx_match, _gpg, pg, sl, entry_mask, _ = _shard_index_probe(
-            t, ix, s, S, key_attrs, attrs, los, his, ts
-        )
-        idx_keep = idx_match & lc[pg]
-        tbl_mask = conj_predicate_mask(t, attrs, los, his) & visible_mask(
-            t, ts
-        )
-        tbl_mask &= (~lc)[:, None]
-        vals = t.data[:, :, agg_attr]
-        idx_sum = jnp.sum(
-            jnp.where(idx_keep, vals[pg, sl], 0), dtype=jnp.int32
-        )
-        tbl_sum = jnp.sum(jnp.where(tbl_mask, vals, 0), dtype=jnp.int32)
-        sums.append(idx_sum + tbl_sum)
-        cnts.append(
-            jnp.sum(idx_keep, dtype=jnp.int32)
-            + jnp.sum(tbl_mask, dtype=jnp.int32)
-        )
-        ents.append(jnp.sum(entry_mask, dtype=jnp.int32))
-        contrib = jnp.zeros((t.n_pages, t.page_size), jnp.int32)
-        contrib = contrib.at[pg, sl].add(idx_keep.astype(jnp.int32))
-        contribs.append(contrib + tbl_mask.astype(jnp.int32))
-    return (
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        tuple(contribs),
-        tree_reduce(ents),
-    )
-
-
-def sharded_hybrid_scan_masked(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-    cov_view,
-) -> ShardScanResult:
-    """Single masked hybrid scan over sharded storage.  Accounting is
-    host-derived from the pinned view: ``pages_scanned`` counts the
-    uncovered pages below the global append watermark, ``start_page``
-    reports the bitmap's leading built run -- both equal the legacy
-    hybrid values whenever the bitmap is a prefix."""
-    s_, c_, contribs, e_ = _sharded_masked_scan(
-        st, index, key_attrs, attrs, los, his, ts, agg_attr, cov_view.mask
-    )
-    used = -(-int(st.n_rows) // st.page_size)
-    pages = int((~cov_view.built_host[:used]).sum())
-    return ShardScanResult(
-        s_,
-        c_,
-        contribs,
-        jnp.asarray(pages, jnp.int32),
-        e_,
-        jnp.asarray(cov_view.prefix_len, jnp.int32),
-    )
-
-
 # ---------------------------------------------------------------------------
 # Stacked batched scans: ONE dispatch for any shard count
 # ---------------------------------------------------------------------------
@@ -448,7 +186,7 @@ def sharded_hybrid_scan_masked(
 # is the same size for 1 shard and 64.  Padding pages (uniform page
 # grid) are invisible (begin_ts == NEVER_TS) and padded index slots
 # sit beyond ``n_entries``, so they add exact int32 zeros; axis
-# reductions replace the loop's pairwise tree reductions bit-exactly
+# reductions equal any other order of per-shard reductions bit-exactly
 # because int32 add / max / min are associative and commutative.
 
 
@@ -876,235 +614,6 @@ def sharded_batched_pure_index_scan(
     six = stacked_shard_indexes(index)
     return _stacked_batched_pure_index(
         stk, six, key_attrs, attrs, los, his, tss, agg_attr
-    )
-
-
-# ---------------------------------------------------------------------------
-# Per-shard loop fan-out (legacy dispatch strategy, kept as the parity
-# oracle and benchmark baseline for the stacked forms above)
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("attrs", "agg_attr"))
-def sharded_batched_full_table_scan_loop(
-    st: ShardedTable, attrs: tuple, los, his, tss, agg_attr: int
-) -> BatchScanResult:
-    """B plain table scans, one fan-out per shard, tree-reduced."""
-    sums, cnts = [], []
-    for t in st.shards:
-
-        def one(lo, hi, ts, t=t):
-            mask = conj_predicate_mask(t, attrs, lo, hi) & visible_mask(t, ts)
-            vals = t.data[:, :, agg_attr]
-            return (
-                jnp.sum(jnp.where(mask, vals, 0), dtype=jnp.int32),
-                jnp.sum(mask, dtype=jnp.int32),
-            )
-
-        s_, c_ = jax.vmap(one)(los, his, tss)
-        sums.append(s_)
-        cnts.append(c_)
-    B = los.shape[0]
-    z = jnp.zeros((B,), jnp.int32)
-    used = jnp.full((B,), _used_pages(st), jnp.int32)
-    return BatchScanResult(tree_reduce(sums), tree_reduce(cnts), used, z, z)
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def sharded_batched_hybrid_scan_loop(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    tss,
-    agg_attr: int,
-) -> BatchScanResult:
-    """B hybrid scans over per-shard partial indexes: pass 1 reduces
-    per-query rho_m across shards into the global stitch point, pass 2
-    fans the deduped index prefix + table suffix out per shard."""
-    S = len(st.shards)
-
-    rho_list = []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-
-        def rho_of(lo, hi, ts, t=t, ix=ix, s=s):
-            return _shard_index_probe(
-                t, ix, s, S, key_attrs, attrs, lo, hi, ts
-            )[5]
-
-        rho_list.append(jax.vmap(rho_of)(los, his, tss))
-    rho_m = tree_reduce(rho_list, jnp.maximum)
-    start_pages = jnp.maximum(rho_m, index.built_pages)  # (B,)
-
-    sums, cnts, ents = [], [], []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-
-        def two(lo, hi, ts, sp, t=t, ix=ix, s=s):
-            idx_match, gpg, pg, sl, entry_mask, _ = _shard_index_probe(
-                t, ix, s, S, key_attrs, attrs, lo, hi, ts
-            )
-            idx_keep = idx_match & (gpg < sp)
-            tbl_mask = _shard_table_mask(t, s, S, attrs, lo, hi, ts, sp)
-            vals = t.data[:, :, agg_attr]
-            s_ = jnp.sum(jnp.where(idx_keep, vals[pg, sl], 0), dtype=jnp.int32)
-            s_ = s_ + jnp.sum(jnp.where(tbl_mask, vals, 0), dtype=jnp.int32)
-            c_ = jnp.sum(idx_keep, dtype=jnp.int32)
-            c_ = c_ + jnp.sum(tbl_mask, dtype=jnp.int32)
-            return s_, c_, jnp.sum(entry_mask, dtype=jnp.int32)
-
-        s_, c_, e_ = jax.vmap(two)(los, his, tss, start_pages)
-        sums.append(s_)
-        cnts.append(c_)
-        ents.append(e_)
-    pages = jnp.clip(_used_pages(st) - start_pages, 0, None).astype(jnp.int32)
-    return BatchScanResult(
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        pages,
-        tree_reduce(ents),
-        start_pages.astype(jnp.int32),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def sharded_batched_hybrid_scan_pershard_loop(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    tss,
-    agg_attr: int,
-) -> BatchScanResult:
-    """B hybrid scans with shard-local stitch points, one vmapped
-    dispatch per shard."""
-    S = len(st.shards)
-    sums, cnts, ents, pages, gstarts = [], [], [], [], []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-
-        def one(lo, hi, ts, t=t, ix=ix, s=s):
-            idx_keep, pg, sl, entry_mask, tbl_mask, pages_s, gstart = (
-                _pershard_stitch(t, ix, s, S, key_attrs, attrs, lo, hi, ts)
-            )
-            vals = t.data[:, :, agg_attr]
-            s_ = jnp.sum(jnp.where(idx_keep, vals[pg, sl], 0), dtype=jnp.int32)
-            s_ = s_ + jnp.sum(jnp.where(tbl_mask, vals, 0), dtype=jnp.int32)
-            c_ = jnp.sum(idx_keep, dtype=jnp.int32)
-            c_ = c_ + jnp.sum(tbl_mask, dtype=jnp.int32)
-            e_ = jnp.sum(entry_mask, dtype=jnp.int32)
-            return s_, c_, e_, pages_s, gstart
-
-        s_, c_, e_, p_, g_ = jax.vmap(one)(los, his, tss)
-        sums.append(s_)
-        cnts.append(c_)
-        ents.append(e_)
-        pages.append(p_)
-        gstarts.append(g_)
-    return BatchScanResult(
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        tree_reduce(pages),
-        tree_reduce(ents),
-        tree_reduce(gstarts, jnp.minimum),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def sharded_batched_pure_index_scan_loop(
-    st: ShardedTable,
-    index: ShardedIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    tss,
-    agg_attr: int,
-) -> BatchScanResult:
-    S = len(st.shards)
-    sums, cnts, ents = [], [], []
-    for s, (t, ix) in enumerate(zip(st.shards, index.shards)):
-
-        def one(lo, hi, ts, t=t, ix=ix, s=s):
-            idx_match, _gpg, pg, sl, entry_mask, _ = _shard_index_probe(
-                t, ix, s, S, key_attrs, attrs, lo, hi, ts
-            )
-            vals = t.data[:, :, agg_attr]
-            match_vals = jnp.where(idx_match, vals[pg, sl], 0)
-            return (
-                jnp.sum(match_vals, dtype=jnp.int32),
-                jnp.sum(idx_match, dtype=jnp.int32),
-                jnp.sum(entry_mask, dtype=jnp.int32),
-            )
-
-        s_, c_, e_ = jax.vmap(one)(los, his, tss)
-        sums.append(s_)
-        cnts.append(c_)
-        ents.append(e_)
-    B = los.shape[0]
-    return BatchScanResult(
-        tree_reduce(sums),
-        tree_reduce(cnts),
-        jnp.zeros((B,), jnp.int32),
-        tree_reduce(ents),
-        jnp.full((B,), st.n_pages, jnp.int32),
-    )
-
-
-# ---------------------------------------------------------------------------
-# Multi-device fan-out (pmap): uniform shards, one device per shard.
-# LEGACY: dispatch now routes every family through the shard_map mesh
-# layer below; the pmap form survives as a parity reference only.
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=32)
-def _pmap_full_scan_fn(attrs: tuple, agg_attr: int):
-    """pmapped per-shard body for the batched full-table scan.  Each
-    device receives one shard's Table (the stacked pytree's leading
-    axis is the device axis); per-query bounds broadcast to every
-    device.  The body is the same mask arithmetic as the stacked
-    fan-out (``conj_predicate_mask``/``visible_mask``), so the two
-    dispatch strategies cannot drift."""
-
-    def body(t, los, his, tss):
-        def one(lo, hi, ts):
-            mask = conj_predicate_mask(t, attrs, lo, hi) & visible_mask(t, ts)
-            vals = t.data[:, :, agg_attr]
-            return (
-                jnp.sum(jnp.where(mask, vals, 0), dtype=jnp.int32),
-                jnp.sum(mask, dtype=jnp.int32),
-            )
-
-        return jax.vmap(one)(los, his, tss)
-
-    return jax.pmap(body, in_axes=(_TABLE_AXES, None, None, None))
-
-
-def shards_uniform(st: ShardedTable) -> bool:
-    return len({t.n_pages for t in st.shards}) == 1
-
-
-def pmap_batched_full_table_scan(
-    st: ShardedTable, attrs: tuple, los, his, tss, agg_attr: int
-) -> BatchScanResult:
-    """Device fan-out: one shard per device via ``jax.pmap``.  Callers
-    must check ``shard_fanout_devices``/``shards_uniform`` first (a
-    uniform layout means the cached stacked pytree carries no padding,
-    so its leading axis is exactly the device axis); the reduced
-    aggregates are bit-identical to the loop fan-out."""
-    stacked = stacked_shards(st).table
-    fn = _pmap_full_scan_fn(attrs, agg_attr)
-    sums, cnts = fn(
-        stacked, jnp.asarray(los), jnp.asarray(his), jnp.asarray(tss)
-    )  # (S, B)
-    B = los.shape[0]
-    z = jnp.zeros((B,), jnp.int32)
-    used = jnp.full((B,), _used_pages(st), jnp.int32)
-    return BatchScanResult(
-        tree_reduce(list(sums)), tree_reduce(list(cnts)), used, z, z
     )
 
 
@@ -1620,14 +1129,13 @@ class ScanEngine:
     """
 
     #: dispatch-strategy vocabulary recorded in ``last_tier``
-    TIERS = ("loop", "vmap-stacked", "kernel", "pmap", "shard_map")
+    TIERS = ("single", "vmap-stacked", "kernel", "shard_map")
 
     def __init__(self):
         self.after_dispatch = None  # () -> None, set by the runner
         # Mesh execution: None = auto (use a mesh whenever the local
         # devices can place one), False = never, True = required (a
-        # placement failure raises instead of silently falling back --
-        # the telemetry fix for the old pmap path's silent downgrade).
+        # placement failure raises instead of silently falling back).
         self.mesh_mode = None
         self.mesh_query_axis = 1  # >1 folds the 2-D query-batch axis
         # Telemetry: the execution tier of the most recent dispatch
@@ -1651,98 +1159,6 @@ class ScanEngine:
                 f"be placed on {len(jax.local_devices())} local devices"
             )
         return mesh
-
-    def scan(self, table, plan, attrs: tuple, los, his, ts, agg_attr: int):
-        """Single planned scan -> ScanResult | ShardScanResult."""
-        path = plan.path
-        if isinstance(table, ShardedTable):
-            self.last_tier = "loop"  # single-query: per-shard operators
-            if path == "table":
-                return sharded_full_table_scan(
-                    table, attrs, los, his, ts, agg_attr
-                )
-            if path in ("pure_vbp", "pure_vap"):
-                return sharded_pure_index_scan(
-                    table,
-                    plan.index_state,
-                    plan.key_attrs,
-                    attrs,
-                    los,
-                    his,
-                    ts,
-                    agg_attr,
-                )
-            if path == "hybrid_ps":
-                return sharded_hybrid_scan_pershard(
-                    table,
-                    plan.index_state,
-                    plan.key_attrs,
-                    attrs,
-                    los,
-                    his,
-                    ts,
-                    agg_attr,
-                )
-            if path == "hybrid_masked":
-                return sharded_hybrid_scan_masked(
-                    table,
-                    plan.index_state,
-                    plan.key_attrs,
-                    attrs,
-                    los,
-                    his,
-                    ts,
-                    agg_attr,
-                    plan.pinned_coverage,
-                )
-            return sharded_hybrid_scan(
-                table,
-                plan.index_state,
-                plan.key_attrs,
-                attrs,
-                los,
-                his,
-                ts,
-                agg_attr,
-            )
-        self.last_tier = "single"
-        if path == "table":
-            return full_table_scan(table, attrs, los, his, ts, agg_attr)
-        if path in ("pure_vbp", "pure_vap"):
-            return pure_index_scan(
-                table,
-                plan.index_state,
-                plan.key_attrs,
-                attrs,
-                los,
-                his,
-                ts,
-                agg_attr,
-            )
-        if path == "hybrid_masked":
-            cov = plan.pinned_coverage
-            return hybrid_scan_masked(
-                table,
-                plan.index_state,
-                plan.key_attrs,
-                attrs,
-                los,
-                his,
-                ts,
-                agg_attr,
-                cov.mask[0],
-                cov.prefix_len,
-            )
-        return hybrid_scan(
-            table,
-            plan.index_state,
-            plan.key_attrs,
-            attrs,
-            los,
-            his,
-            ts,
-            agg_attr,
-        )
 
     def dispatch_complete(self) -> None:
         """Between-dispatch drain point.  The executor calls this after

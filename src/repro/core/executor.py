@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core import cost_model as cm
 from repro.core.cost_model import IndexDescriptor
-from repro.core.engine import ScanEngine, ShardScanResult
+from repro.core.engine import ScanEngine
 from repro.core.index import (
     ShardedIndex,
     ShardedVbpState,
@@ -63,6 +63,7 @@ from repro.core.planner import (
 from repro.core.table import (
     ShardedTable,
     Table,
+    conj_predicate_mask,
     copy_table,
     insert_rows,
     round_robin_layout,
@@ -72,6 +73,7 @@ from repro.core.table import (
     table_bytes,
     unshard_table,
     update_rows,
+    visible_mask,
 )
 from repro.obs import host_read, span
 
@@ -445,36 +447,41 @@ class Database:
             index=bi.desc.name if bi is not None else "",
             queries=1,
         ) as sp:
-            los = jnp.asarray(q.los, jnp.int32)
-            his = jnp.asarray(q.his, jnp.int32)
-            r = self.engine.scan(
+            los = jnp.asarray([q.los], jnp.int32)
+            his = jnp.asarray([q.his], jnp.int32)
+            r = self.engine.scan_batch(
                 t,
-                plan,
+                plan.path,
+                plan.index_state,
+                plan.key_attrs,
                 tuple(q.attrs),
                 los,
                 his,
-                self.clock_ms_i32(),
+                self.clock_ms_i32()[None],
                 q.agg_attr,
+                coverage=plan.pinned_coverage,
             )
             sp.set_metadata(tier=self.engine.last_tier or "")
 
         if plan.path == "table":
             start_page, entries = 0, 0.0
         elif plan.path in ("hybrid", "hybrid_ps", "hybrid_masked"):
-            start_page = int(host_read(r.start_page, "executor.scan.start"))
+            start_page = int(
+                host_read(r.start_page, "executor.scan.start")[0]
+            )
             entries = float(
-                int(host_read(r.entries_probed, "executor.scan.entries"))
+                int(host_read(r.entries_probed, "executor.scan.entries")[0])
             )
         else:  # pure index scan: no table pages touched
             start_page = t.n_pages
             entries = float(
-                int(host_read(r.entries_probed, "executor.scan.entries"))
+                int(host_read(r.entries_probed, "executor.scan.entries")[0])
             )
         cost = scan_cost(
             layout,
             q.accessed_attrs,
             t.page_size,
-            int(host_read(r.pages_scanned, "executor.scan.pages")),
+            int(host_read(r.pages_scanned, "executor.scan.pages")[0]),
             entries,
             start_page,
         )
@@ -484,9 +491,9 @@ class Database:
         if used:
             bi.last_used_ms = self.clock_ms
 
-        count = int(host_read(r.count, "executor.scan.count"))
+        count = int(host_read(r.count, "executor.scan.count")[0])
         if q.join_table is not None:
-            count, join_cost, join_used = self._exec_join(q, r)
+            count, join_cost, join_used = self._exec_join(q)
             cost += join_cost
             used = used or join_used
         lat_ms = cost * self.time_per_unit_ms
@@ -502,7 +509,7 @@ class Database:
             cost_units=cost,
             latency_ms=lat_ms,
             used_index=used,
-            agg_sum=int(host_read(r.agg_sum, "executor.scan.sum")),
+            agg_sum=int(host_read(r.agg_sum, "executor.scan.sum")[0]),
             count=count,
             populate_units=populate,
             shard_pages=self._shard_pages_of(t, plan),
@@ -790,7 +797,7 @@ class Database:
                 )
             out[pos] = stats
 
-    def _exec_join(self, q: Query, outer):
+    def _exec_join(self, q: Query):
         """HIGH-S equi-join: count pairs between the outer matches and
         the inner table on join_attr == join_inner_attr.  Cost model:
         index-nested-loop when an index exists on the inner join
@@ -798,36 +805,32 @@ class Database:
         inner_t = self.tables[q.join_table]
         outer_t = self.tables[q.table]
         ts = int(self.clock_ms) + 1
-        # exact pair count (host-side sorted merge; correctness path)
-        if isinstance(outer, ShardScanResult):
-            outer_vals = np.concatenate(
-                [
-                    np.asarray(t.data[:, :, q.join_attr])[np.asarray(c) > 0]
-                    for t, c in zip(outer_t.shards, outer.contribs)
+        # exact pair count (host-side sorted merge; correctness path).
+        # The outer rows are the scan's: predicate and visibility at its
+        # snapshot, which the stitch counts exactly once.
+        snap = self.clock_ms_i32()
+
+        def parts(t):
+            return t.shards if isinstance(t, ShardedTable) else (t,)
+
+        def flat(planes):
+            return np.concatenate([np.asarray(p).reshape(-1) for p in planes])
+
+        outer_vals = np.concatenate(
+            [
+                np.asarray(t.data[:, :, q.join_attr])[
+                    np.asarray(
+                        conj_predicate_mask(t, q.attrs, q.los, q.his)
+                        & visible_mask(t, snap)
+                    )
                 ]
-            )
-        else:
-            om = np.asarray(outer.contrib) > 0
-            outer_vals = np.asarray(outer_t.data[:, :, q.join_attr])[om]
-        if isinstance(inner_t, ShardedTable):
-            ib = np.concatenate(
-                [np.asarray(t.begin_ts).reshape(-1) for t in inner_t.shards]
-            )
-            ie = np.concatenate(
-                [np.asarray(t.end_ts).reshape(-1) for t in inner_t.shards]
-            )
-            ivals = np.concatenate(
-                [
-                    np.asarray(t.data[:, :, q.join_inner_attr]).reshape(-1)
-                    for t in inner_t.shards
-                ]
-            )
-        else:
-            ib = np.asarray(inner_t.begin_ts).reshape(-1)
-            ie = np.asarray(inner_t.end_ts).reshape(-1)
-            ivals = np.asarray(inner_t.data[:, :, q.join_inner_attr]).reshape(
-                -1
-            )
+                for t in parts(outer_t)
+            ]
+        )
+        inner = parts(inner_t)
+        ib = flat(t.begin_ts for t in inner)
+        ie = flat(t.end_ts for t in inner)
+        ivals = flat(t.data[:, :, q.join_inner_attr] for t in inner)
         ivis = (ib <= ts) & (ts < ie)
         inner_vals = np.sort(ivals[ivis])
         lo = np.searchsorted(inner_vals, outer_vals, side="left")

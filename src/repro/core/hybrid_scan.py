@@ -50,27 +50,13 @@ from repro.core.index import AdHocIndex, index_range_scan, key_range
 from repro.core.table import Table, conj_predicate_mask, visible_mask
 
 
-class ScanResult(NamedTuple):
-    """Aggregates + accounting from one scan execution."""
-
-    agg_sum: jax.Array  # () int64 SUM(a_k) over matches
-    count: jax.Array  # () int32 number of matching rows
-    contrib: jax.Array  # (n_pages, page_size) int32 -- times each row
-    # was returned (must be 0/1; tested)
-    pages_scanned: jax.Array  # () int32 table pages touched
-    entries_probed: jax.Array  # () int32 index entries touched
-    start_page: jax.Array  # () int32 where the table scan began
-
-
 class BatchScanResult(NamedTuple):
     """Per-query aggregates + accounting from one batched execution.
 
-    Every field is (n_queries,); entry q is bit-identical to the
-    corresponding ``ScanResult`` field of the per-query operator run
-    with query q's bounds and snapshot (``contrib`` is not
-    materialised on the batch path -- the executor only needs the
-    aggregates and accounting, and the per-query oracle equivalence
-    is covered by tests/test_batch_exec.py).
+    Every field is (n_queries,); entry q is what the family's
+    per-query body (``_hybrid_scan_core``, ``_masked_scan_core`` or
+    the vmapped ``one``) returns for query q's bounds and snapshot.
+    A single statement runs as a batch of one.
     """
 
     agg_sum: jax.Array  # (B,) int32
@@ -111,9 +97,9 @@ def _hybrid_scan_core(
     agg_attr: int,
 ):
     """Shared hybrid-scan body: returns the aggregate/accounting tuple
-    plus the match masks the single-query wrapper needs for contrib.
-    The batched path vmaps this and drops the masks (XLA prunes the
-    dead mask computation after jit)."""
+    plus the match masks (the exactly-once tests read them).  The
+    batched path vmaps this and drops the masks (XLA prunes the dead
+    mask computation after jit)."""
     psz = table.page_size
     lo_key, hi_key = _predicate_key_bounds(key_attrs, attrs, los, his)
 
@@ -161,87 +147,6 @@ def _hybrid_scan_core(
     return stats, idx_keep, tbl_mask, pg, sl
 
 
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def hybrid_scan(
-    table: Table,
-    index: AdHocIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-) -> ScanResult:
-    """Value-agnostic hybrid scan: index prefix + table suffix."""
-    stats, idx_keep, tbl_mask, pg, sl = _hybrid_scan_core(
-        table, index, key_attrs, attrs, los, his, ts, agg_attr
-    )
-    agg_sum, count, pages_scanned, entries_probed, start_page = stats
-    contrib = jnp.zeros((table.n_pages, table.page_size), jnp.int32)
-    contrib = contrib.at[pg, sl].add(idx_keep.astype(jnp.int32))
-    contrib = contrib + tbl_mask.astype(jnp.int32)
-    return ScanResult(
-        agg_sum, count, contrib, pages_scanned, entries_probed, start_page
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def pure_index_scan(
-    table: Table,
-    index: AdHocIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-) -> ScanResult:
-    """Index-only scan -- legal only when the index covers the predicate
-    (FULL scheme with a complete index, or VBP with a covered
-    sub-domain)."""
-    psz = table.page_size
-    lo_key, hi_key = _predicate_key_bounds(key_attrs, attrs, los, his)
-    entry_mask, rids = index_range_scan(index, lo_key, hi_key)
-    pg, sl = rids // psz, rids % psz
-    rows_ok = conj_predicate_mask(table, attrs, los, his)[pg, sl]
-    rows_ok &= visible_mask(table, ts)[pg, sl]
-    idx_match = entry_mask & rows_ok
-    contrib = jnp.zeros((table.n_pages, table.page_size), jnp.int32)
-    contrib = contrib.at[pg, sl].add(idx_match.astype(jnp.int32))
-    vals = table.data[:, :, agg_attr]
-    s = jnp.sum(jnp.where(idx_match, vals[pg, sl], 0), dtype=jnp.int32)
-    c = jnp.sum(idx_match, dtype=jnp.int32)
-    return ScanResult(
-        s,
-        c,
-        contrib,
-        jnp.zeros((), jnp.int32),
-        jnp.sum(entry_mask, dtype=jnp.int32),
-        jnp.asarray(table.n_pages, jnp.int32),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("attrs", "agg_attr"))
-def full_table_scan(
-    table: Table, attrs: tuple, los, his, ts, agg_attr: int
-) -> ScanResult:
-    """Plain table scan (no usable index)."""
-    tbl_mask = conj_predicate_mask(table, attrs, los, his)
-    tbl_mask &= visible_mask(table, ts)
-    vals = table.data[:, :, agg_attr]
-    s = jnp.sum(jnp.where(tbl_mask, vals, 0), dtype=jnp.int32)
-    c = jnp.sum(tbl_mask, dtype=jnp.int32)
-    used_pages = (table.n_rows + table.page_size - 1) // table.page_size
-    return ScanResult(
-        s,
-        c,
-        tbl_mask.astype(jnp.int32),
-        used_pages.astype(jnp.int32),
-        jnp.zeros((), jnp.int32),
-        jnp.zeros((), jnp.int32),
-    )
-
-
 # ---------------------------------------------------------------------------
 # Batched multi-query scans (the executor's read-burst substrate)
 # ---------------------------------------------------------------------------
@@ -250,8 +155,8 @@ def full_table_scan(
 # jitted dispatch over shared table state -- the launch-amortisation
 # analogue of the Pallas kernel in kernels/batched_filter_agg.py
 # (which the ops layer exposes for TPU deployments; on CPU these
-# vmapped forms are the fast path).  Results are per-query
-# bit-identical to the single-query operators above.
+# vmapped forms are the fast path).  A single statement runs as a
+# batch of one.
 
 
 @functools.partial(jax.jit, static_argnames=("attrs", "agg_attr"))
@@ -347,34 +252,6 @@ def _masked_scan_core(
         jnp.asarray(prefix_len, jnp.int32),
     )
     return stats, idx_keep, tbl_mask, pg, sl
-
-
-@functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
-def hybrid_scan_masked(
-    table: Table,
-    index: AdHocIndex,
-    key_attrs: tuple,
-    attrs: tuple,
-    los,
-    his,
-    ts,
-    agg_attr: int,
-    covered,
-    prefix_len,
-) -> ScanResult:
-    """Bitmap-stitched hybrid scan: index over covered pages, table
-    scan over exactly the uncovered ones."""
-    stats, idx_keep, tbl_mask, pg, sl = _masked_scan_core(
-        table, index, key_attrs, attrs, los, his, ts, agg_attr,
-        covered, prefix_len
-    )
-    agg_sum, count, pages_scanned, entries_probed, start_page = stats
-    contrib = jnp.zeros((table.n_pages, table.page_size), jnp.int32)
-    contrib = contrib.at[pg, sl].add(idx_keep.astype(jnp.int32))
-    contrib = contrib + tbl_mask.astype(jnp.int32)
-    return ScanResult(
-        agg_sum, count, contrib, pages_scanned, entries_probed, start_page
-    )
 
 
 @functools.partial(jax.jit, static_argnames=("key_attrs", "attrs", "agg_attr"))
@@ -508,8 +385,9 @@ def batched_pure_index_scan(
     tss,
     agg_attr: int,
 ) -> BatchScanResult:
-    """B index-only scans in one dispatch (same legality conditions as
-    ``pure_index_scan``)."""
+    """B index-only scans in one dispatch -- legal only when the index
+    covers the predicate (FULL scheme with a complete index, or VBP
+    with a covered sub-domain)."""
     psz = table.page_size
 
     def one(lo, hi, ts):

@@ -690,7 +690,7 @@ def build_pages_remaining(state, table) -> int:
 # stitch relies on.  Shard-aware tuning relaxes it: each shard's local
 # prefix advances independently (budget routed by forecast per-shard
 # utility), and the hybrid scan stitches per shard instead
-# (engine.sharded_hybrid_scan_pershard).  Every shard still builds its
+# (engine._pershard_stitch).  Every shard still builds its
 # own local pages strictly in order, so the per-shard in-order
 # invariant -- the one correctness actually needs -- is untouched.
 
@@ -1054,7 +1054,7 @@ def index_range_scan(index: AdHocIndex, lo: KeyPair, hi: KeyPair):
     ``entry_mask`` is (capacity,) bool over the sorted entry array;
     callers gather rows via ``rids`` and must re-check the predicate
     and MVCC visibility against the table (stored keys can be stale
-    for updated rows; see hybrid_scan).
+    for updated rows; see ``core.hybrid_scan``).
     """
     ar = jnp.arange(index.capacity, dtype=jnp.int32)
     mask = keys_in_range(index.key_hi, index.key_lo, lo, hi)

@@ -1,10 +1,10 @@
 """Pallas TPU kernels: multi-query fused filter+aggregate table scans.
 
 One launch evaluates a whole *batch* of conjunctive filter+aggregate
-queries over the same column planes.  The per-query dispatch path
-(``filter_agg``) is launch-bound on read bursts -- every query pays a
-kernel launch plus a fresh HBM -> VMEM stream of the same columns.
-Batching amortises both:
+queries over the same column planes; a single query is a batch of
+one.  A launch per query would be launch-bound on read bursts -- every
+query would pay a kernel launch plus a fresh HBM -> VMEM stream of the
+same columns.  Batching amortises both:
 
 * Grid is ``(page_block, query)`` with the query dimension innermost:
   consecutive grid steps share the same input block, so Pallas keeps
@@ -46,8 +46,7 @@ burst is ONE launch regardless of shard count:
   them out of the sums.
 
 Semantics contract: ``ref.batched_filter_agg_ref`` -- per query
-identical to ``ref.masked_filter_agg_ref``.  A single-query batch is
-bit-identical to the single-query kernel; a single-shard launch is
+identical to ``ref.masked_filter_agg_ref``.  A single-shard launch is
 bit-identical to the plain batched kernel.
 """
 

@@ -1,11 +1,12 @@
 """Jit'd public wrappers around the Pallas scan kernels.
 
-``scan_table`` / ``scan_table_hybrid`` adapt the engine's Table layout
-(columns stacked in one (n_pages, page_size, n_attrs) array) to the
-kernels' column-plane interface and pick hardware-aligned block shapes;
-``scan_table_batched`` is the multi-query form and
-``scan_shards_batched`` the fused multi-shard multi-query form over a
-stacked shard pytree (``core.table.stacked_shards``).
+``scan_table_batched`` adapts the engine's Table layout (columns
+stacked in one (n_pages, page_size, n_attrs) array) to the multi-query
+kernel's column-plane interface and picks hardware-aligned block
+shapes; a single query is a batch of one.  ``scan_shards_batched`` is
+the fused multi-shard form over a stacked shard pytree
+(``core.table.stacked_shards``); the ``*_masked`` forms scan the
+pages a coverage bitmap leaves uncovered.
 
 Every wrapper's ``interpret=None`` resolves through ``interpret_mode``:
 the Pallas interpreter runs exactly when JAX's default backend is the
@@ -19,10 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import batched_filter_agg as _bfa
-from repro.kernels import filter_agg as _fa
 
-I32_MIN = _fa.I32_MIN
-I32_MAX = _fa.I32_MAX
+I32_MIN = _bfa.I32_MIN
+I32_MAX = _bfa.I32_MAX
 
 def interpret_mode() -> bool:
     """Run the Pallas kernels in interpret mode?  True only when JAX's
@@ -51,19 +51,6 @@ def _block_pages(n_pages, page_size, interpret, n_planes=5):
     return _bfa.tpu_block_pages(n_pages, page_size, n_planes=n_planes)
 
 
-def _single_bounds(table, attrs, los, his):
-    """Predicate planes + widened bounds for a single-query scan."""
-    pred0 = table.data[:, :, attrs[0]]
-    lo0, hi0 = los[0], his[0]
-    if len(attrs) == 2:
-        pred1 = table.data[:, :, attrs[1]]
-        lo1, hi1 = los[1], his[1]
-    else:
-        pred1 = pred0
-        lo1, hi1 = I32_MIN, I32_MAX
-    return pred0, pred1, lo0, hi0, lo1, hi1
-
-
 def _batch_bounds(data, attrs, los, his):
     """Split per-query (B, len(attrs)) bounds into the kernels' two
     predicate-plane/bounds pairs (1-attr queries widen the second)."""
@@ -80,57 +67,6 @@ def _batch_bounds(data, attrs, los, his):
         los1 = jnp.full((n_queries,), I32_MIN, jnp.int32)
         his1 = jnp.full((n_queries,), I32_MAX, jnp.int32)
     return pred0, pred1, los0, his0, los1, his1
-
-
-def scan_table(table, attrs, los, his, ts, agg_attr, interpret=None):
-    """Full-table filter+aggregate via the Pallas kernel.
-
-    ``table`` is a repro.core.table.Table; ``attrs`` constrains 1 or 2
-    columns with inclusive bounds los/his.
-    """
-    interpret = interpret_mode() if interpret is None else interpret
-    pred0, pred1, lo0, hi0, lo1, hi1 = _single_bounds(table, attrs, los, his)
-    agg = table.data[:, :, agg_attr]
-    return _fa.filter_agg(
-        pred0,
-        pred1,
-        agg,
-        table.begin_ts,
-        table.end_ts,
-        lo0,
-        hi0,
-        lo1,
-        hi1,
-        ts,
-        block_pages=_block_pages(table.n_pages, table.page_size, interpret),
-        interpret=interpret,
-    )
-
-
-def scan_table_hybrid(
-    table, attrs, los, his, ts, agg_attr, start_page, interpret=None
-):
-    """The hybrid scan's table-scan suffix: pages >= start_page only.
-    Blocks fully inside the indexed prefix are skipped pre-DMA via the
-    scalar-prefetched ``start_page``."""
-    interpret = interpret_mode() if interpret is None else interpret
-    pred0, pred1, lo0, hi0, lo1, hi1 = _single_bounds(table, attrs, los, his)
-    agg = table.data[:, :, agg_attr]
-    return _fa.filter_agg(
-        pred0,
-        pred1,
-        agg,
-        table.begin_ts,
-        table.end_ts,
-        lo0,
-        hi0,
-        lo1,
-        hi1,
-        ts,
-        start_page=jnp.asarray(start_page, jnp.int32),
-        block_pages=_block_pages(table.n_pages, table.page_size, interpret),
-        interpret=interpret,
-    )
 
 
 def scan_table_batched(

@@ -239,30 +239,3 @@ def named_shardings(mesh, params):
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda s: isinstance(s, P))
 
-
-# ---------------------------------------------------------------------------
-# Storage-shard fan-out (the bench_db engine's per-shard dispatch)
-# ---------------------------------------------------------------------------
-#
-# Unlike the model-parallel rules above -- which shard *tensors of one
-# program* across a mesh -- the storage engine shards *pages of one
-# table* across a shard list and fans one scan dispatch out per shard
-# (core/engine.py).  On a single device that fan-out is a loop inside
-# one jitted program; when every shard can own a device, the engine
-# lifts the fan-out onto the device axis via ``jax.pmap``.  These
-# helpers are the only place the engine asks about devices, so the
-# policy (and its guard) lives next to the rest of the mesh plumbing.
-
-def shard_fanout_devices(n_shards: int):
-    """Devices for a one-device-per-shard fan-out, or None.
-
-    Returns the first ``n_shards`` local devices when enough exist
-    (the pmap path needs exactly one device per shard); None means the
-    caller must keep the single-device loop fan-out.
-    """
-    if n_shards < 2:
-        return None
-    devices = jax.local_devices()
-    if len(devices) < n_shards:
-        return None
-    return devices[:n_shards]

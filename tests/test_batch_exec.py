@@ -18,14 +18,14 @@ from repro.bench_db.runner import RunConfig, run_workload
 from repro.bench_db.workloads import hybrid_workload
 from repro.core import Database, IndexDescriptor
 from repro.core.baselines import DisabledTuner
-from repro.core.hybrid_scan import (batched_full_table_scan,
-                                    batched_hybrid_scan, full_table_scan,
-                                    hybrid_scan)
-from repro.core.index import build_pages_vap, make_index
+from repro.core.hybrid_scan import (_hybrid_scan_core,
+                                    batched_full_table_scan,
+                                    batched_hybrid_scan)
+from repro.core.index import build_pages_vap, eligible_global_pages, make_index
 from repro.core.table import load_table
 from repro.kernels import ops
 from repro.kernels.batched_filter_agg import batched_filter_agg
-from repro.kernels.ref import batched_filter_agg_ref
+from repro.kernels.ref import batched_filter_agg_ref, filter_agg_ref
 
 SRC = make_tuner_db(n_rows=4_000, page_size=128)
 
@@ -139,7 +139,67 @@ def test_batch_kernel_path_matches_vmapped():
 
 
 # ---------------------------------------------------------------------------
-# batched scan operators vs single-query operators (full accounting)
+# execute(scan) is execute_batch([scan]): one operator per plan family
+# ---------------------------------------------------------------------------
+
+def _family_db(family, num_shards):
+    """A database whose planner serves ``_family_query`` by ``family``."""
+    db = Database(dict(SRC.tables), num_shards=num_shards)
+    db.index_decay = family == "hybrid_masked"   # attaches the bitmap
+    desc = IndexDescriptor("narrow", (1,))
+    n_pages = SRC.tables["narrow"].n_pages
+    if family in ("hybrid", "hybrid_masked"):
+        bi = db.create_index(desc, "vap")
+        picks = eligible_global_pages(db.tables["narrow"])[::3]
+        db.vap_build_step(bi, pages=len(picks), page_list=(
+            picks if family == "hybrid_masked" else None))
+    elif family == "hybrid_ps":
+        bi = db.create_index(desc, "vap")
+        for shard, pages in ((0, 5), (2, 2)):
+            db.vap_build_step(bi, pages=pages, shard=shard)
+    elif family == "pure_vap":
+        db.vap_build_step(db.create_index(desc, "full"), pages=n_pages)
+    elif family == "pure_vbp":
+        bi = db.create_index(desc, "vbp")
+        for k in range(3):
+            db.vbp_populate(bi, _family_query(k),
+                            max_add=SRC.tables["narrow"].capacity)
+    return db
+
+
+def _family_query(k):
+    gen = QueryGen(SRC, selectivity=0.01, seed=31 + k)
+    return gen.low_s(attr=1, pos=0.3 + 0.01 * k)
+
+
+@pytest.mark.parametrize("family,num_shards", [
+    (family, num_shards)
+    for num_shards in (1, 3)
+    for family in ("table", "hybrid", "hybrid_ps", "hybrid_masked",
+                   "pure_vap", "pure_vbp")
+    # the per-shard stitch exists on sharded tables only
+    if num_shards > 1 or family != "hybrid_ps"
+])
+def test_execute_is_batch_of_one(family, num_shards):
+    """Every ExecStats field of ``execute(scan)`` equals
+    ``execute_batch([scan])[0]``, and so do the clock and the monitor,
+    for each plan family on a plain and a 3-shard table."""
+    db_one, db_batch = _family_db(family, num_shards), \
+        _family_db(family, num_shards)
+    for k in range(3):
+        q = _family_query(k)
+        plan = db_one.planner.plan_scan(q)
+        assert plan.path == family or (
+            family == "table" and plan.index is None), plan.path
+        a = db_one.execute(q)
+        (b,) = db_batch.execute_batch([q])
+        assert a == b, (family, num_shards, k, a, b)
+    assert db_one.clock_ms == db_batch.clock_ms
+    assert list(db_one.monitor.records) == list(db_batch.monitor.records)
+
+
+# ---------------------------------------------------------------------------
+# batched scan operators vs one call of the per-query body (accounting)
 # ---------------------------------------------------------------------------
 
 def test_batched_hybrid_scan_accounting_fields():
@@ -154,13 +214,11 @@ def test_batched_hybrid_scan_accounting_fields():
     r = batched_hybrid_scan(t, idx, (1,), (1,), jnp.asarray(los),
                             jnp.asarray(his), jnp.asarray(tss), 2)
     for k in range(4):
-        one = hybrid_scan(t, idx, (1,), (1,), jnp.asarray(los[k]),
-                          jnp.asarray(his[k]), 0, 2)
-        assert int(r.agg_sum[k]) == int(one.agg_sum)
-        assert int(r.count[k]) == int(one.count)
-        assert int(r.pages_scanned[k]) == int(one.pages_scanned)
-        assert int(r.entries_probed[k]) == int(one.entries_probed)
-        assert int(r.start_page[k]) == int(one.start_page)
+        stats, *_ = _hybrid_scan_core(t, idx, (1,), (1,),
+                                      jnp.asarray(los[k]),
+                                      jnp.asarray(his[k]), 0, 2)
+        got = [int(field[k]) for field in r]
+        assert got == [int(x) for x in stats], (k, got, stats)
 
 
 def test_batched_full_scan_accounting_fields():
@@ -172,12 +230,13 @@ def test_batched_full_scan_accounting_fields():
     r = batched_full_table_scan(t, (1, 2), jnp.asarray(los),
                                 jnp.asarray(his),
                                 jnp.zeros(2, jnp.int32), 3)
+    used_pages = -(-50 // 8)
     for k in range(2):
-        one = full_table_scan(t, (1, 2), jnp.asarray(los[k]),
-                              jnp.asarray(his[k]), 0, 3)
-        assert int(r.agg_sum[k]) == int(one.agg_sum)
-        assert int(r.count[k]) == int(one.count)
-        assert int(r.pages_scanned[k]) == int(one.pages_scanned)
+        ref_sum, ref_count = filter_agg_ref(
+            t.data[:, :, 1], t.data[:, :, 2], t.data[:, :, 3], t.begin_ts,
+            t.end_ts, los[k, 0], his[k, 0], los[k, 1], his[k, 1], 0)
+        got = [int(field[k]) for field in r]
+        assert got == [int(ref_sum), int(ref_count), used_pages, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -238,15 +297,19 @@ def test_batched_kernel_block_skip_boundaries():
 
 
 def test_batched_kernel_single_query_matches_single_kernel():
+    """A batch of one through the ops wrapper, two predicate columns,
+    with and without a stitch point, against the jnp oracle."""
     t = _mk_planes(512, 128, seed=4)
     for sp in (0, 3):
         sb, cb = ops.scan_table_batched(
             t, (1, 2), np.array([[100, 200]], np.int32),
             np.array([[700, 900]], np.int32), np.zeros(1, np.int32), 4,
             start_pages=np.array([sp], np.int32))
-        s1, c1 = ops.scan_table_hybrid(t, (1, 2), (100, 200), (700, 900),
-                                       ts=0, agg_attr=4, start_page=sp)
-        assert (int(sb[0]), int(cb[0])) == (int(s1), int(c1))
+        rs, rc = batched_filter_agg_ref(
+            t.data[:, :, 1], t.data[:, :, 2], t.data[:, :, 4], t.begin_ts,
+            t.end_ts, jnp.array([100]), jnp.array([700]), jnp.array([200]),
+            jnp.array([900]), jnp.zeros(1, jnp.int32), jnp.array([sp]))
+        assert (int(sb[0]), int(cb[0])) == (int(rs[0]), int(rc[0]))
 
 
 def test_batched_kernel_respects_mvcc_per_query():

@@ -11,7 +11,7 @@ from repro.core.classifier import (READ_INTENSIVE, UNKNOWN, WRITE_INTENSIVE,
                                    default_training_set)
 from repro.core.index import (build_pages_vap, key_range, make_index,
                               make_vbp, vbp_populate_subdomain)
-from repro.core.hybrid_scan import pure_index_scan
+from repro.core.hybrid_scan import batched_pure_index_scan
 from repro.core.table import load_table
 
 
@@ -129,11 +129,14 @@ def test_vbp_overlapping_populates_never_duplicate():
         klo, khi = key_range(lo, hi)
         vbp, _ = vbp_populate_subdomain(vbp, t, (1,), klo, khi, 0,
                                         max_add=t.capacity)
-        r = pure_index_scan(t, vbp.index, (1,), (1,),
-                            jnp.array([lo]), jnp.array([hi]), 0, 2)
-        assert int(r.contrib.max()) <= 1
+        n = int(vbp.index.n_entries)
+        rids = np.asarray(vbp.index.rids)[:n]
+        assert len(np.unique(rids)) == n, "an index entry is duplicated"
+        r = batched_pure_index_scan(t, vbp.index, (1,), (1,),
+                                    jnp.array([[lo]]), jnp.array([[hi]]),
+                                    jnp.zeros(1, jnp.int32), 2)
         m = (vals[:, 1] >= lo) & (vals[:, 1] <= hi)
-        assert int(r.count) == int(m.sum())
+        assert int(r.count[0]) == int(m.sum())
 
 
 def test_vap_never_indexes_partial_watermark_page():

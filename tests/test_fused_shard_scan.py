@@ -1,16 +1,20 @@
 """Fused single-dispatch sharded scans: parity + cache invariants.
 
 Contract: the stacked single-dispatch forms (and the fused Pallas
-kernel in interpret mode) are BIT-identical to the per-shard loop
-fan-out for every batched scan family, for uniform round-robin AND
-skewed pre-sharded layouts, including ``hybrid_ps`` with divergent
-per-shard built prefixes -- in results and in every accounting field.
+kernel in interpret mode) are BIT-identical to a per-shard loop
+fan-out over the shards tuple (``loop_reference`` below) for every
+batched scan family, for uniform round-robin AND skewed pre-sharded
+layouts, including ``hybrid_ps`` with divergent per-shard built
+prefixes -- in results and in every accounting field.
 The stacked/padded shard pytree is cached per shards-tuple identity
 and invalidated by every mutator.
 """
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from benchmarks.shard_tuning import make_skewed_db
@@ -63,13 +67,57 @@ def _assert_results_equal(a, b, label):
 
 
 FAMILIES = ("table", "hybrid", "hybrid_ps", "pure_vap")
+I32_MAX = 2**31 - 1
 
-LOOP_FNS = {
-    "table": eng.sharded_batched_full_table_scan_loop,
-    "hybrid": eng.sharded_batched_hybrid_scan_loop,
-    "hybrid_ps": eng.sharded_batched_hybrid_scan_pershard_loop,
-    "pure_vap": eng.sharded_batched_pure_index_scan_loop,
-}
+
+def loop_reference(path, st, ix, los, his, tss):
+    """The per-shard loop fan-out on key and predicate a_1, SUM(a_2):
+    each unpadded shard of the tuple runs the batch on its own, and the
+    partials reduce in shard order.  The stacked forms' reference."""
+    S, B = st.n_shards, los.shape[0]
+    shards = list(zip(st.shards, ix.shards))
+    probe = functools.partial(eng._shard_index_probe, S=S, key_attrs=(1,),
+                              attrs=(1,))
+    if path == "hybrid":  # pass 1: the global stitch point
+        rho = [jax.vmap(lambda lo, hi, ts, t=t, x=x, s=s: probe(
+            t, x, s, lo=lo, hi=hi, ts=ts)[5])(los, his, tss)
+            for s, (t, x) in enumerate(shards)]
+        starts = jnp.maximum(functools.reduce(jnp.maximum, rho),
+                             ix.built_pages)
+    else:  # a table scan starts at 0; an index-only scan never starts
+        starts = jnp.full((B,), 0 if path == "table" else I32_MAX)
+
+    def one(t, x, s, lo, hi, ts, sp):
+        z = jnp.zeros((), jnp.int32)
+        if path == "hybrid_ps":
+            keep, pg, sl, ents, tbl, pages, gstart = eng._pershard_stitch(
+                t, x, s, S, (1,), (1,), lo, hi, ts)
+        else:
+            tbl = eng._shard_table_mask(t, s, S, (1,), lo, hi, ts, sp)
+            match, gpg, pg, sl, ents, _ = probe(t, x, s, lo=lo, hi=hi, ts=ts)
+            keep = match & (gpg < sp) & (path != "table")
+            ents, pages, gstart = ents & (path != "table"), z, z
+        vals = t.data[:, :, 2]
+        return (jnp.sum(jnp.where(keep, vals[pg, sl], 0), dtype=jnp.int32)
+                + jnp.sum(jnp.where(tbl, vals, 0), dtype=jnp.int32),
+                jnp.sum(keep, dtype=jnp.int32) + jnp.sum(tbl, dtype=jnp.int32),
+                jnp.sum(ents, dtype=jnp.int32), pages, gstart)
+
+    parts = [jax.vmap(functools.partial(one, t, x, s))(los, his, tss, starts)
+             for s, (t, x) in enumerate(shards)]
+    sums, cnts, ents, pages, gstarts = (
+        functools.reduce(op, p) for op, p in zip(
+            (jnp.add,) * 4 + (jnp.minimum,), zip(*parts)))
+    used = jnp.full((B,), eng._used_pages(st))
+    zeros = jnp.zeros((B,), jnp.int32)
+    return eng.BatchScanResult(sums, cnts, *{
+        "table": (used, ents, zeros),
+        "hybrid": (jnp.clip(used - starts, 0, None), ents, starts),
+        "hybrid_ps": (pages, ents, gstarts),
+        "pure_vap": (zeros, ents, jnp.full((B,), st.n_pages)),
+    }[path])
+
+
 STACKED_FNS = {
     "table": eng.sharded_batched_full_table_scan,
     "hybrid": eng.sharded_batched_hybrid_scan,
@@ -94,7 +142,7 @@ def test_stacked_matches_loop_uniform(num_shards, path):
     db, bi = _mk_db(num_shards=num_shards, build_pages=9)
     st = db.tables["narrow"]
     los, his, tss = _bounds(6, seed=num_shards)
-    a = _run_family(LOOP_FNS[path], path, st, bi.vap, los, his, tss)
+    a = loop_reference(path, st, bi.vap, los, his, tss)
     b = _run_family(STACKED_FNS[path], path, st, bi.vap, los, his, tss)
     _assert_results_equal(a, b, f"{path}@S{num_shards}")
 
@@ -108,7 +156,7 @@ def test_stacked_matches_loop_skewed(path):
     st = db.tables["narrow"]
     assert len({t.n_pages for t in st.shards}) > 1  # genuinely ragged
     los, his, tss = _bounds(5, seed=11, width=40_000)
-    a = _run_family(LOOP_FNS[path], path, st, bi.vap, los, his, tss)
+    a = loop_reference(path, st, bi.vap, los, his, tss)
     b = _run_family(STACKED_FNS[path], path, st, bi.vap, los, his, tss)
     _assert_results_equal(a, b, f"skewed.{path}")
 
@@ -121,8 +169,7 @@ def test_stacked_hybrid_ps_divergent_prefixes():
     assert bi.desc.name in db.pershard_built
     st = db.tables["narrow"]
     los, his, tss = _bounds(8, seed=23)
-    a = _run_family(LOOP_FNS["hybrid_ps"], "hybrid_ps", st, bi.vap,
-                    los, his, tss)
+    a = loop_reference("hybrid_ps", st, bi.vap, los, his, tss)
     b = _run_family(STACKED_FNS["hybrid_ps"], "hybrid_ps", st, bi.vap,
                     los, his, tss)
     _assert_results_equal(a, b, "divergent.hybrid_ps")

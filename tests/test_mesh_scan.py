@@ -9,8 +9,7 @@ per-shard loop oracle by test_fused_shard_scan.  Device counts are
 forced via ``--xla_force_host_platform_device_count`` in fresh
 subprocesses (XLA reads it at import time).  The in-process half
 covers placement fallback, the mesh_mode=True hard-require knob, and
-the execution-tier telemetry that replaces the old pmap path's silent
-downgrade.
+the execution-tier telemetry (no silent downgrade to another tier).
 """
 import os
 import subprocess
@@ -105,10 +104,49 @@ _IDENTITY_SCRIPT = """
 """
 
 
-def test_mesh_bit_identity_4dev_subprocess():
-    """4 shards on a 4-device mesh (one shard per device), plus the
-    2-D shard x query-batch mesh, for all four families."""
-    _run(_IDENTITY_SCRIPT % 4, 4, "MESH_IDENTITY_OK")
+# One statement at a time on 4 shards: each scan dispatches as a batch
+# of one onto the mesh (tier shard_map) and matches the one-shard
+# engine in results, accounting and monitor records.
+_ONE_STATEMENT_SCRIPT = """
+    from repro.bench_db import QueryGen, make_tuner_db
+    from repro.core import Database, IndexDescriptor
+
+    SRC = make_tuner_db(n_rows=2_000, page_size=128)
+    qs = [QueryGen(SRC, selectivity=sel, seed=3).low_s(attr=1)
+          for sel in (0.01, 0.01, 0.5, 0.01, 0.5, 0.01)]
+
+    def mk(num_shards):
+        db = Database(dict(SRC.tables), num_shards=num_shards)
+        bi = db.create_index(IndexDescriptor("narrow", (1,)), "vap")
+        db.vap_build_step(bi, pages=5)
+        return db
+
+    def key(s):
+        return (s.agg_sum, s.count, s.cost_units, s.latency_ms,
+                s.used_index)
+
+    ref_db, db = mk(1), mk(4)
+    ref = [ref_db.execute(q) for q in qs]
+    got = [db.execute(q) for q in qs]
+    assert [key(s) for s in got] == [key(s) for s in ref], (got, ref)
+    assert all(s.tier == "shard_map" for s in got), got
+    assert {s.used_index for s in got} == {True, False}
+    assert db.clock_ms == ref_db.clock_ms
+    assert list(db.monitor.records) == list(ref_db.monitor.records)
+    print("MESH_IDENTITY_OK")
+"""
+
+
+@pytest.mark.parametrize(
+    "script",
+    [_IDENTITY_SCRIPT % 4, _ONE_STATEMENT_SCRIPT],
+    ids=["families", "one_statement"],
+)
+def test_mesh_bit_identity_4dev_subprocess(script):
+    """4 shards on a 4-device mesh (one shard per device): the four
+    families batched, on the 1-D and the 2-D shard x query-batch mesh;
+    and single statements through ``execute``, each a batch of one."""
+    _run(script, 4, "MESH_IDENTITY_OK")
 
 
 def test_mesh_bit_identity_2dev_subprocess():

@@ -17,6 +17,7 @@ import: only one process at a time may load the TPU library, and the
 worker that runs this file keeps it until it exits.
 """
 
+import functools
 import os
 import re
 
@@ -32,7 +33,6 @@ from repro.core import engine
 from repro.core.index import AdHocIndex, _build_pages_vap
 from repro.core.table import StackedShards, Table
 from repro.kernels import batched_filter_agg as bfa
-from repro.kernels import filter_agg as fa
 from repro.kernels import ops
 from repro.parallel.mesh import SHARD_AXIS
 
@@ -89,29 +89,32 @@ def test_block_pages_for_the_paper_table():
     assert BLOCK_PAGES == 256
 
 
-@pytest.mark.parametrize("hybrid", [False, True], ids=["full", "hybrid"])
-def test_filter_agg_compiles(one_chip, hybrid):
+@pytest.mark.parametrize(
+    "batch, starts",
+    [(BATCH, True), (1, False), (1, True)],
+    ids=["batch", "one_full", "one_hybrid"],
+)
+def test_batched_filter_agg_compiles(one_chip, batch, starts):
+    """A burst, and one statement as a batch of one: a table scan (no
+    stitch points; the kernel gets zeros, as ``ops.scan_table_batched``
+    passes it) and a hybrid scan's table suffix."""
     plane = _i32((N_PAGES, PAGE_SIZE), one_chip)
-    scalar = _i32((), one_chip)
-    start = scalar if hybrid else None
-    _assert_mosaic(
-        fa.filter_agg.lower(
-            *[plane] * 5,
-            *[scalar] * 5,
-            start,
-            block_pages=BLOCK_PAGES,
-            interpret=False,
+    per_query = _i32((batch,), one_chip)
+    program, operands = bfa.batched_filter_agg, [per_query] * 6
+    if not starts:
+
+        @functools.partial(
+            jax.jit, static_argnames=("block_pages", "interpret")
         )
-    )
+        def program(*args, **kw):
+            zeros = jnp.zeros((batch,), jnp.int32)
+            return bfa.batched_filter_agg(*args, zeros, **kw)
 
-
-def test_batched_filter_agg_compiles(one_chip):
-    plane = _i32((N_PAGES, PAGE_SIZE), one_chip)
-    per_query = _i32((BATCH,), one_chip)
+        operands = operands[:5]
     compiled = _assert_mosaic(
-        bfa.batched_filter_agg.lower(
+        program.lower(
             *[plane] * 5,
-            *[per_query] * 6,
+            *operands,
             block_pages=BLOCK_PAGES,
             interpret=False,
         )
